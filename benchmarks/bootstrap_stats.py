@@ -7,11 +7,12 @@ Measures, for the full bootstrap aggregation of a task's metrics
 * wall-clock — steady-state, after a same-shape warmup so the pallas
   backend's one-time XLA compile is amortized the way it is across a
   streaming run's chunks;
-* peak host allocation — each point runs in a fresh spawned subprocess
-  and reports its ``ru_maxrss`` high-water mark minus a baseline
-  subprocess (same imports, same data, no engine work), which captures
+* peak host allocation — the process's resident-set high-water mark
+  (``VmHWM``, reset through ``/proc/self/clear_refs`` before the measured
+  pass) minus its resident set at the start of the pass, which captures
   allocations tracemalloc cannot see (XLA buffers); the Python-heap
-  tracemalloc peak is reported alongside.
+  tracemalloc peak is reported alongside.  Everything runs in this one
+  process, so on an accelerator host no child competes for the device.
 
 Also cross-checks CI endpoints of the two weight streams (host Philox vs
 kernel counter-mixer, run both natively and through the Pallas
@@ -24,8 +25,7 @@ Emits ``BENCH_stats.json``.
 
 from __future__ import annotations
 
-import json
-import multiprocessing as mp
+import gc
 import time
 
 from benchmarks import artifacts
@@ -69,55 +69,37 @@ def _aggregate(backend: str, scores: dict, n_boot: int) -> list:
     return out
 
 
-def _point_worker(backend: str, n: int, n_boot: int, q) -> None:
-    """One measurement in a clean process: warmup, then a measured pass."""
-    import resource
-    import tracemalloc
-
-    scores = _make_scores(n)
-    if backend:
-        _aggregate(backend, scores, n_boot)  # warmup: XLA compile, pools
-        tracemalloc.start()
-        t0 = time.perf_counter()
-        cis = _aggregate(backend, scores, n_boot)
-        wall = time.perf_counter() - t0
-        _, py_peak = tracemalloc.get_traced_memory()
-        tracemalloc.stop()
-    else:  # baseline: imports + data only
-        from repro.stats import make_bootstrap_engine  # noqa: F401
-
-        wall, py_peak, cis = 0.0, 0, []
-    q.put({
-        "wall_s": wall,
-        "py_heap_peak_mb": py_peak / 1e6,
-        "ru_maxrss_mb": resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
-        / 1024,
-        "cis": cis,
-    })
+def _status_mb(field: str) -> float:
+    """A ``/proc/self/status`` memory field (``VmRSS``, ``VmHWM``) in MB."""
+    with open("/proc/self/status") as f:
+        for line in f:
+            if line.startswith(field + ":"):
+                return int(line.split()[1]) / 1024
+    raise KeyError(field)
 
 
 def _measure(backend: str, n: int, n_boot: int) -> dict:
-    import queue as queue_mod
+    """One measurement: warmup, then a measured pass."""
+    import tracemalloc
 
-    ctx = mp.get_context("spawn")
-    q = ctx.Queue()
-    p = ctx.Process(target=_point_worker, args=(backend, n, n_boot, q))
-    p.start()
-    try:
-        # bounded wait: an OOM-killed subprocess must fail the benchmark,
-        # not hang the CI job on a queue that will never be fed
-        out = q.get(timeout=600)
-    except queue_mod.Empty:
-        p.terminate()
-        p.join()
-        raise RuntimeError(
-            f"measurement subprocess produced no result: {backend} n={n} "
-            f"B={n_boot} (exitcode={p.exitcode}; killed/OOM?)"
-        ) from None
-    p.join()
-    if p.exitcode != 0:
-        raise RuntimeError(f"measurement subprocess failed: {backend} {n}")
-    return out
+    scores = _make_scores(n)
+    _aggregate(backend, scores, n_boot)  # warmup: XLA compile, pools
+    gc.collect()
+    with open("/proc/self/clear_refs", "w") as f:
+        f.write("5")  # reset VmHWM to the current resident set
+    base = _status_mb("VmRSS")
+    tracemalloc.start()
+    t0 = time.perf_counter()
+    cis = _aggregate(backend, scores, n_boot)
+    wall = time.perf_counter() - t0
+    _, py_peak = tracemalloc.get_traced_memory()
+    tracemalloc.stop()
+    return {
+        "wall_s": wall,
+        "py_heap_peak_mb": py_peak / 1e6,
+        "rss_peak_mb": _status_mb("VmHWM") - base,
+        "cis": cis,
+    }
 
 
 def _parity_check(n: int = 2000, n_boot: int = 300) -> dict:
@@ -166,20 +148,15 @@ def run(*, smoke: bool = False) -> list[str]:
 
     lines: list[str] = []
     rows = []
-    baselines: dict[int, float] = {}
     for n, n_boot in points:
-        if n not in baselines:
-            baselines[n] = _measure("", n, n_boot)["ru_maxrss_mb"]
         row: dict = {"n": n, "n_boot": n_boot, "n_metrics": N_METRICS}
         for backend in ("numpy", "pallas"):
             r = _measure(backend, n, n_boot)
             row[backend] = {
                 "wall_s": r["wall_s"],
                 "py_heap_peak_mb": r["py_heap_peak_mb"],
-                # the path's own high-water allocation over the baseline
-                "host_alloc_mb": max(
-                    r["ru_maxrss_mb"] - baselines[n], r["py_heap_peak_mb"]
-                ),
+                # the path's own high-water allocation over the pass's start
+                "host_alloc_mb": max(r["rss_peak_mb"], r["py_heap_peak_mb"]),
             }
         row["speedup"] = row["numpy"]["wall_s"] / max(
             row["pallas"]["wall_s"], 1e-9

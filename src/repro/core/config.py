@@ -83,11 +83,12 @@ class InferenceConfig:
     #: requests (False = paged allocation only, no cross-request reuse)
     prefix_cache: bool = True
     #: KV page storage precision: "bf16" = full-precision pages, "int8" =
-    #: absmax block-quantized pages + per-(page, head) f32 scales,
-    #: dequantized in-kernel at decode (DESIGN.md §10).  Halves
-    #: bytes-per-token, so the same pool byte budget admits ~2x pages;
-    #: requires kv_page_size > 0.  Outputs stay byte-identical across
-    #: replicas/routing/page sizes at *fixed* dtype; int8-vs-bf16 parity
+    #: absmax block-quantized pages + per-(page, head) f32 scales; decode
+    #: gathers a slot's pages and dequantizes them in jax.numpy before
+    #: attention (DESIGN.md §10).  Halves bytes-per-token, so the same
+    #: pool byte budget admits ~2x pages; requires kv_page_size > 0.
+    #: Outputs stay byte-identical across replicas/routing/page sizes at
+    #: *fixed* dtype; int8-vs-bf16 parity
     #: is a tolerance + token-match-rate gate, not bit equality.
     kv_cache_dtype: str = "bf16"
     # fault tolerance for the serving fabric (DESIGN.md §9):

@@ -27,6 +27,7 @@ import time
 from typing import Any
 
 from repro.core.config import EngineModelConfig, InferenceConfig
+from repro.data.tokenizer import HashTokenizer
 
 # -- request/response ---------------------------------------------------------
 
@@ -806,6 +807,11 @@ class LocalJaxEngine(InferenceEngine):
         self.initialized = False
         self._scheduler = None
         self._tokenizer = None
+        #: one tokenizer per in-flight request: a hash tokenizer decodes
+        #: only words it has encoded, so decoding with the request's own
+        #: makes the response a function of the request alone, not of
+        #: which prompts this replica happened to encode before
+        self._request_tok: dict[int, HashTokenizer] = {}
         self._next_id = 0
         # worker threads share one scheduler; it is the batching layer, so
         # concurrent infer_batch calls serialize (slots multiplex inside)
@@ -817,9 +823,8 @@ class LocalJaxEngine(InferenceEngine):
         import jax
 
         from repro.configs import get_config
-        from repro.data.tokenizer import HashTokenizer
         from repro.models import params as pm
-        from repro.models.model import build_model
+        from repro.models.model import build_model, serving_param_specs
         from repro.serve.scheduler import ContinuousBatcher
 
         cfg = get_config(self.model_cfg.model_name)
@@ -828,9 +833,6 @@ class LocalJaxEngine(InferenceEngine):
         self._cfg = cfg
         self._tokenizer = HashTokenizer(cfg.vocab_size)
         model = build_model(cfg, remat="none")
-        params = pm.init_params(
-            jax.random.key(self.model_cfg.seed), model.param_specs()
-        )
         device = rules = None
         if self.devices and len(self.devices) == 1:
             device = self.devices[0]
@@ -840,6 +842,14 @@ class LocalJaxEngine(InferenceEngine):
 
             rules = ShardingRules(
                 SERVE_RULES, make_replica_mesh(self.devices)
+            )
+        # a pinned replica builds its weights on its own device: building
+        # them on the default device first would stack every replica's
+        # weights there before they move
+        with jax.default_device(device):
+            params = pm.init_params(
+                jax.random.key(self.model_cfg.seed),
+                serving_param_specs(model.param_specs()),
             )
         self._scheduler = ContinuousBatcher(
             model, cfg, params,
@@ -871,7 +881,9 @@ class LocalJaxEngine(InferenceEngine):
         self.initialize()
         rid = self._next_id
         self._next_id += 1
-        toks = self._tokenizer.encode(request.prompt)[: self.max_len // 2]
+        tok = HashTokenizer(self._cfg.vocab_size)
+        self._request_tok[rid] = tok
+        toks = tok.encode(request.prompt)[: self.max_len // 2]
         self._scheduler.submit(
             Request(
                 request_id=rid,
@@ -885,7 +897,7 @@ class LocalJaxEngine(InferenceEngine):
 
     def _completion_response(self, c) -> InferenceResponse:
         return InferenceResponse(
-            text=self._tokenizer.decode(c.tokens),
+            text=self._request_tok.pop(c.request_id).decode(c.tokens),
             input_tokens=c.prompt_len,
             output_tokens=len(c.tokens),
             latency_ms=c.latency_s * 1000.0,
@@ -958,7 +970,10 @@ class LocalJaxEngine(InferenceEngine):
     def stream_cancel(self, rid: int) -> bool:
         with self._lock:
             sched = self._scheduler
-            return bool(sched) and sched.cancel(rid)
+            cancelled = bool(sched) and sched.cancel(rid)
+            if cancelled:
+                del self._request_tok[rid]
+            return cancelled
 
     def reset(self) -> None:
         """Replica-restart hook: rebuild the scheduler (fresh slots, fresh
@@ -966,6 +981,7 @@ class LocalJaxEngine(InferenceEngine):
         re-attached by ``initialize`` so scheduled faults keep firing."""
         with self._lock:
             self._scheduler = None
+            self._request_tok.clear()
             self.initialized = False
             self.initialize()
 

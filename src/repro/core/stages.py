@@ -420,7 +420,6 @@ class InferStage:
         inf = task.inference
         model = task.model
         prompts = art.prompts
-        session.engine_for(model)  # engine init parity with the legacy path
         service = session.service_for(model, inf)
         cache = session.cache_for(inf)
         limiter = session.limiter_for(inf)

@@ -31,11 +31,11 @@ def _normalize(x: jax.Array) -> jax.Array:
 
 def _kernel(
     cand_ref,   # (1, Lc, D)
-    cmask_ref,  # (1, Lc)
+    cmask_ref,  # (1, Lc, 1) — column layout: masks rows of the sim tile
     refs_ref,   # (1, bLr, D)
-    rmask_ref,  # (1, bLr)
-    p_ref,      # (1, 1) out — precision
-    r_ref,      # (1, 1) out — recall
+    rmask_ref,  # (1, 1, bLr) — row layout: masks columns of the sim tile
+    p_ref,      # (1, 1, 1) out — precision
+    r_ref,      # (1, 1, 1) out — recall
     rowmax_ref,  # VMEM (Lc, 1) f32 — running max over ref tiles
     colsum_ref,  # VMEM (1, 1) f32 — sum of col maxima (ref tokens)
     colcnt_ref,  # VMEM (1, 1) f32 — count of valid ref tokens
@@ -52,27 +52,31 @@ def _kernel(
 
     c = _normalize(cand_ref[0].astype(jnp.float32))   # (Lc, D)
     r = _normalize(refs_ref[0].astype(jnp.float32))   # (bLr, D)
-    cm = cmask_ref[0].astype(jnp.float32) > 0.5       # (Lc,)
-    rm = rmask_ref[0].astype(jnp.float32) > 0.5       # (bLr,)
+    cm = cmask_ref[0] > 0.5                            # (Lc, 1)
+    rm = rmask_ref[0] > 0.5                            # (1, bLr)
 
     sim = jax.lax.dot_general(
         c, r, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Lc, bLr)
-    sim = jnp.where(cm[:, None] & rm[None, :], sim, NEG_INF)
+    sim = jnp.where(cm & rm, sim, NEG_INF)
 
-    rowmax_ref[:, 0] = jnp.maximum(rowmax_ref[:, 0], jnp.max(sim, axis=1))
-    col_max = jnp.max(sim, axis=0)  # (bLr,)
-    colsum_ref[0, 0] += jnp.sum(jnp.where(rm, col_max, 0.0))
-    colcnt_ref[0, 0] += jnp.sum(rm.astype(jnp.float32))
+    rowmax_ref[...] = jnp.maximum(
+        rowmax_ref[...], jnp.max(sim, axis=1, keepdims=True)
+    )
+    col_max = jnp.max(sim, axis=0, keepdims=True)  # (1, bLr)
+    colsum_ref[...] += jnp.sum(jnp.where(rm, col_max, 0.0), keepdims=True)
+    colcnt_ref[...] += jnp.sum(rm.astype(jnp.float32), keepdims=True)
 
     @pl.when(it == n_tiles - 1)
     def _final():
-        cmf = cm.astype(jnp.float32)
-        denom_c = jnp.maximum(jnp.sum(cmf), 1.0)
-        p_ref[0, 0] = jnp.sum(
-            jnp.where(cm, rowmax_ref[:, 0], 0.0)
-        ) / denom_c
-        r_ref[0, 0] = colsum_ref[0, 0] / jnp.maximum(colcnt_ref[0, 0], 1.0)
+        denom_c = jnp.maximum(
+            jnp.sum(cm.astype(jnp.float32), keepdims=True), 1.0
+        )
+        p_ref[0] = (
+            jnp.sum(jnp.where(cm, rowmax_ref[...], 0.0), keepdims=True)
+            / denom_c
+        )
+        r_ref[0] = colsum_ref[...] / jnp.maximum(colcnt_ref[...], 1.0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -95,22 +99,25 @@ def bertscore_pr(
         ref_mask = jnp.pad(ref_mask, ((0, 0), (0, pad)))
 
     kernel = functools.partial(_kernel, n_tiles=n_tiles)
+    # every block's last two dims are (8, 128)-aligned or span the array:
+    # masks travel as (B, Lc, 1) columns / (B, 1, Lr) rows, P and R as
+    # (B, 1, 1)
     p, r = pl.pallas_call(
         kernel,
         grid=(b, n_tiles),
         in_specs=[
             pl.BlockSpec((1, lc, d), lambda ib, it: (ib, 0, 0)),
-            pl.BlockSpec((1, lc), lambda ib, it: (ib, 0)),
+            pl.BlockSpec((1, lc, 1), lambda ib, it: (ib, 0, 0)),
             pl.BlockSpec((1, br, d), lambda ib, it: (ib, it, 0)),
-            pl.BlockSpec((1, br), lambda ib, it: (ib, it)),
+            pl.BlockSpec((1, 1, br), lambda ib, it: (ib, 0, it)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda ib, it: (ib, 0)),
-            pl.BlockSpec((1, 1), lambda ib, it: (ib, 0)),
+            pl.BlockSpec((1, 1, 1), lambda ib, it: (ib, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda ib, it: (ib, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((lc, 1), jnp.float32),
@@ -120,8 +127,8 @@ def bertscore_pr(
         interpret=interpret,
     )(
         cand,
-        cand_mask.astype(jnp.float32),
+        cand_mask.astype(jnp.float32)[:, :, None],
         ref,
-        ref_mask.astype(jnp.float32),
+        ref_mask.astype(jnp.float32)[:, None, :],
     )
-    return p[:, 0], r[:, 0]
+    return p[:, 0, 0], r[:, 0, 0]

@@ -42,8 +42,12 @@ def mix_bits(boot: jax.Array, pos: jax.Array, seed: jax.Array) -> jax.Array:
 
 
 def poisson1_weight(bits: jax.Array) -> jax.Array:
-    """Map uniform u32 bits -> Poisson(1) draw via inverse CDF (k <= 7)."""
-    u = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    """Map uniform u32 bits -> Poisson(1) draw via inverse CDF (k <= 7).
+
+    ``bits >> 8`` fits in 24 bits, so the cast through int32 is exact; it
+    is there because Mosaic has no uint32 -> float32 conversion."""
+    top24 = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    u = top24.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
     w = jnp.zeros_like(u)
     for c in POISSON1_CDF:
         w = w + (u >= jnp.float32(c)).astype(jnp.float32)

@@ -101,7 +101,7 @@ def _measure(
                 out_shardings=cell.out_shardings,
             ).lower(*cell.args)
         compiled = lowered.compile()
-        cost = hlo_analysis.normalize_cost_analysis(compiled.cost_analysis())
+        cost = compiled.cost_analysis() or {}
         coll = hlo_analysis.collective_stats(compiled.as_text(), mesh.size)
     return {
         "flops": float(cost.get("flops", 0.0)),

@@ -65,7 +65,7 @@ def run_cell(
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = hlo_analysis.normalize_cost_analysis(compiled.cost_analysis())
+        cost = compiled.cost_analysis() or {}
         hlo_text = compiled.as_text()
 
     coll = hlo_analysis.collective_stats(hlo_text, chips)

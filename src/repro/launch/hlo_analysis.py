@@ -24,14 +24,6 @@ import dataclasses
 import re
 from collections import defaultdict
 
-
-def normalize_cost_analysis(cost) -> dict:
-    """``compiled.cost_analysis()`` returns a dict on current jax but a
-    one-element list of dicts on jax < 0.5 — normalize to a dict."""
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost or {})
-
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1,
     "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,

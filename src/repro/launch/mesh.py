@@ -11,25 +11,14 @@ from typing import Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 exposes explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -38,7 +27,7 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     data = min(data, n)
     model = max(1, min(model, n // data))
     return jax.make_mesh(
-        (data, model), ("data", "model"), **_axis_type_kwargs(2)
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
     )
 
 

@@ -1,7 +1,11 @@
-"""Serving driver: continuous-batching generation for any assigned arch.
+"""Serving driver: continuous-batching generation of QA prompts for a
+text-only transformer arch (dense, MoE, MLA), at bf16 serving precision.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --reduced \\
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \\
       --requests 12 --slots 4 --max-new 16
+
+serves the reduced config; ``--no-reduced`` serves the published widths,
+which needs an accelerator with room for the weights (qwen3-4b: ~8 GB).
 """
 
 from __future__ import annotations
@@ -14,15 +18,17 @@ import jax
 from repro.configs import get_config
 from repro.core.ratelimit import TokenBucket
 from repro.data import HashTokenizer, qa_examples
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as pm
-from repro.models.model import build_model
+from repro.models.model import build_model, serving_param_specs
 from repro.serve import ContinuousBatcher, Request
 
 
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen3-4b")
-    p.add_argument("--reduced", action="store_true", default=True)
+    p.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                   default=True)
     p.add_argument("--requests", type=int, default=12)
     p.add_argument("--slots", type=int, default=4)
     p.add_argument("--max-new", type=int, default=16)
@@ -31,12 +37,15 @@ def main() -> None:
     p.add_argument("--admission-tpm", type=float, default=0.0,
                    help=">0 enables token-bucket admission control")
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = build_model(cfg, remat="none")
-    params = pm.init_params(jax.random.key(0), model.param_specs())
+    params = pm.init_params(
+        jax.random.key(0), serving_param_specs(model.param_specs())
+    )
     tok = HashTokenizer(cfg.vocab_size)
 
     admission = None

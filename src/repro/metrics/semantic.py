@@ -8,8 +8,9 @@ identical across processes/hosts.  On a real deployment the embedder is
 swappable for model-based encoders (the LocalJaxEngine exposes hidden
 states; see ``model_embedder``).
 
-BERTScore greedy matching runs through ``repro/kernels/bertscore`` (Pallas
-on TPU, jnp oracle on CPU).
+BERTScore greedy matching runs through ``repro/kernels/bertscore``: with
+``use_pallas`` the Pallas kernel (native on a TPU, interpreted elsewhere),
+otherwise the jnp oracle.
 """
 
 from __future__ import annotations
@@ -94,10 +95,14 @@ def bertscore_f1(
         cand[i], cmask[i] = emb.embed_tokens(p, max_len)
         ref[i], rmask[i] = emb.embed_tokens(r, max_len)
     if use_pallas:
+        import jax
+
         from repro.kernels.bertscore import bertscore
 
+        # native kernel on a TPU; the Pallas interpreter everywhere else
         _, _, f1 = bertscore(
-            cand, ref, cmask, rmask, use_pallas=True, interpret=True
+            cand, ref, cmask, rmask, use_pallas=True,
+            interpret=jax.default_backend() != "tpu",
         )
     else:
         _, _, f1 = bertscore_ref(cand, ref, cmask, rmask)

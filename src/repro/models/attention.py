@@ -1,14 +1,14 @@
 """Attention blocks: GQA/MQA/MHA, MLA (multi-head latent), cross-attention.
 
-Three execution paths:
+Two execution paths for full-sequence attention (``impl``):
 
 * ``naive``    — full score matrix; only safe for short sequences.
 * ``chunked``  — double-scan (q-blocks x k-blocks) online-softmax, the jnp
   twin of the Pallas flash kernel; default for train/prefill. O(block²)
-  memory instead of O(S²).
-* ``pallas``   — the TPU kernel in :mod:`repro.kernels.flash_attention`
-  (selected via config; dry-run always uses a jnp path because Mosaic does
-  not lower on the CPU backend).
+  memory instead of O(S²).  Sequences shorter than 512 take the naive path.
+
+The Pallas flash kernel (:mod:`repro.kernels.flash_attention`) is not wired
+into the model; any other ``impl`` raises.
 
 Decode paths use a pre-allocated KV cache, per-sequence positions (so the
 continuous-batching scheduler can step ragged batches), and — for MLA — the
@@ -30,6 +30,14 @@ from repro.models.params import ParamSpec
 from repro.models.unroll import maybe_scan
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+ATTN_IMPLS = ("naive", "chunked")
+
+
+def _use_chunked(impl: str, seq_len: int) -> bool:
+    if impl not in ATTN_IMPLS:
+        raise ValueError(f"unknown attention impl {impl!r}; expected {ATTN_IMPLS}")
+    return impl == "chunked" and seq_len >= 512
 
 
 # ---------------------------------------------------------------------------
@@ -160,14 +168,8 @@ def decode_attention(
     return naive_attention(q, k_cache, v_cache, mask, scale)
 
 
-def make_causal_mask(
-    sq: int, sk: int, prefix_len: int = 0, q_offset: int = 0
-) -> jax.Array:
-    """``q_offset`` > 0 places the queries at global positions
-    ``q_offset .. q_offset+sq`` over ``sk`` keys starting at position 0 —
-    the suffix-prefill mask (queries see the whole cached prefix plus the
-    causal part of their own block)."""
-    q_pos = jnp.arange(sq)[:, None] + q_offset
+def make_causal_mask(sq: int, sk: int, prefix_len: int = 0) -> jax.Array:
+    q_pos = jnp.arange(sq)[:, None]
     k_pos = jnp.arange(sk)[None, :]
     visible = q_pos >= k_pos
     if prefix_len:
@@ -247,7 +249,7 @@ def gqa_full(
     else:
         k, v = kv
     scale = cfg.head_dim**-0.5
-    if impl == "chunked" and s >= 512:
+    if _use_chunked(impl, s):
         out = chunked_attention(
             q, k, v, causal=causal, scale=scale, prefix_len=prefix_len
         )
@@ -260,26 +262,24 @@ def gqa_full(
     return layers.dense(params["wo"], out.reshape(b, s, -1))
 
 
-def gqa_suffix(
+def gqa_chunk(
     params: dict,
     cfg: ModelConfig,
-    x: jax.Array,      # (B, s, D) — hidden states of the prompt *suffix*
-    k_ctx: jax.Array,  # (B, start+s, K, dh) — cached prefix ++ fresh suffix
-    v_ctx: jax.Array,  # (B, start+s, K, dh)
-    start: int,
+    x: jax.Array,          # (B, W, D) — hidden states of one prompt chunk
+    k_ctx: jax.Array,      # (B, S, K, dh) — the whole cache row, chunk included
+    v_ctx: jax.Array,      # (B, S, K, dh)
+    positions: jax.Array,  # (W,) global positions of the chunk's rows
 ) -> jax.Array:
-    """Suffix prefill attention: queries at global positions
-    ``start .. start+s`` attend over the full context ``[0, start+s)``.
-    Because a transformer's suffix hidden states depend on the prefix only
-    through the prefix KV, this reproduces what full prefill would compute
-    for the same positions (DESIGN.md §8)."""
-    b, s, _ = x.shape
-    positions = jnp.arange(start, start + s)[None, :]
-    q = gqa_project_q(params, cfg, x, positions if cfg.pos_emb == "rope" else None)
-    mask = make_causal_mask(s, k_ctx.shape[1], q_offset=start)
+    """Chunk prefill attention: each query attends to every cache position
+    at or before its own.  The shapes depend only on the chunk width and
+    the cache length, never on where the chunk starts (DESIGN.md §8)."""
+    b, w, _ = x.shape
+    rope_pos = positions[None, :] if cfg.pos_emb == "rope" else None
+    q = gqa_project_q(params, cfg, x, rope_pos)
+    mask = jnp.arange(k_ctx.shape[1])[None, :] <= positions[:, None]  # (W, S)
     out = naive_attention(q, k_ctx, v_ctx, mask, cfg.head_dim**-0.5)
     out = sharding.constrain(out, ("batch", "seq", "heads", None))
-    return layers.dense(params["wo"], out.reshape(b, s, -1))
+    return layers.dense(params["wo"], out.reshape(b, w, -1))
 
 
 def gqa_init_cache(
@@ -397,7 +397,7 @@ def mla_full(
     )
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     scale = (nope + rope_d) ** -0.5
-    if impl == "chunked" and s >= 512:
+    if _use_chunked(impl, s):
         out = chunked_attention(q, k, v, causal=causal, scale=scale)
     else:
         mask = make_causal_mask(s, s) if causal else None

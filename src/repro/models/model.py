@@ -32,7 +32,7 @@ from repro import sharding
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import layers, moe, ssm
-from repro.models.params import ParamSpec, is_spec
+from repro.models.params import ParamSpec, is_spec, map_with_path
 from repro.models.unroll import maybe_scan
 
 PyTree = Any
@@ -120,17 +120,17 @@ def tblock_cache_specs(
     return attn.gqa_init_cache(cfg, batch, max_len, dtype)
 
 
-def _fill(cache: jax.Array, new: jax.Array, start: int = 0) -> jax.Array:
-    """Write the prompt's projected values into the cache at ``start``.
+def _fill(cache: jax.Array, new: jax.Array) -> jax.Array:
+    """Write the prompt's projected values into the cache.
 
     When the prompt covers the whole cache the update is a plain cast —
     avoiding a dynamic-update-slice the SPMD partitioner would otherwise
     service with an involuntary full rematerialization (observed on the
     MQA kv=1 prefill cells)."""
     s = new.shape[1]
-    if start == 0 and s == cache.shape[1]:
+    if s == cache.shape[1]:
         return new.astype(cache.dtype)
-    return cache.at[:, start : start + s].set(new.astype(cache.dtype))
+    return cache.at[:, :s].set(new.astype(cache.dtype))
 
 
 def tblock_prefill(
@@ -141,20 +141,12 @@ def tblock_prefill(
     *,
     prefix_len: int = 0,
     impl: str = "chunked",
-    start: int = 0,
 ) -> tuple[jax.Array, dict, jax.Array]:
-    """Forward + cache fill (inference prefill).
-
-    ``start`` > 0 is *suffix prefill*: ``x`` holds positions
-    ``start .. start+s`` of the prompt and ``cache`` already contains the
-    first ``start`` positions' KV (gathered from shared prefix pages);
-    only GQA attention supports it."""
+    """Forward + cache fill (inference prefill)."""
     b, s, _ = x.shape
-    positions = jnp.arange(start, start + s)[None, :]
+    positions = jnp.arange(s)[None, :]
     h = layers.rms_norm(params["ln1"], x, cfg.norm_eps)
     if cfg.use_mla:
-        if start:
-            raise ValueError("suffix prefill is not supported for MLA caches")
         c_kv, k_rope = attn._mla_ckv(params["attn"], cfg, h, positions)
         cache = {
             "c_kv": _fill(cache["c_kv"], c_kv),
@@ -164,34 +156,63 @@ def tblock_prefill(
     else:
         rope_pos = positions if cfg.pos_emb == "rope" else None
         k, v = attn.gqa_project_kv(params["attn"], cfg, h, rope_pos)
-        cache = {
-            "k": _fill(cache["k"], k, start),
-            "v": _fill(cache["v"], v, start),
-        }
-        if start:
-            if prefix_len:
-                raise ValueError(
-                    "suffix prefill cannot combine with a prefix-LM mask"
-                )
-            # cache stores post-rope keys, so prefix ++ fresh-suffix concat
-            # is position-consistent; the round-trip through the cache dtype
-            # is exact (values originate in the compute dtype)
-            k_ctx = jnp.concatenate([cache["k"][:, :start].astype(k.dtype), k], 1)
-            v_ctx = jnp.concatenate([cache["v"][:, :start].astype(v.dtype), v], 1)
-            a = attn.gqa_suffix(params["attn"], cfg, h, k_ctx, v_ctx, start)
-        else:
-            a = attn.gqa_full(
-                params["attn"], cfg, h, causal=True, prefix_len=prefix_len,
-                impl=impl, kv=(k, v),
-            )
-    x = x + a
+        cache = {"k": _fill(cache["k"], k), "v": _fill(cache["v"], v)}
+        a = attn.gqa_full(
+            params["attn"], cfg, h, causal=True, prefix_len=prefix_len,
+            impl=impl, kv=(k, v),
+        )
+    x, aux = _tblock_ffn(params, cfg, x + a)
+    return x, cache, aux
+
+
+def _tblock_ffn(
+    params: dict, cfg: ModelConfig, x: jax.Array
+) -> tuple[jax.Array, jax.Array]:
+    """The block's second half: ``x + ffn(norm(x))`` and the MoE aux loss."""
     h = layers.rms_norm(params["ln2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if "moe" in params:
         y, aux = moe.moe_block(params["moe"], cfg, h)
     else:
         y = layers.gated_mlp(params["mlp"], h, cfg.act)
-    return x + y, cache, aux
+    return x + y, aux
+
+
+def tblock_prefill_chunk(
+    params: dict,
+    cfg: ModelConfig,
+    x: jax.Array,          # (B, W, D) — one chunk of the prompt
+    cache: dict,           # {"k", "v"}: (B, S, K, dh), positions < start filled
+    positions: jax.Array,  # (W,) start + arange(W)
+    length: jax.Array,     # () prompt length; rows at or past it are padding
+) -> tuple[jax.Array, dict]:
+    """Prefill one fixed-width chunk of a GQA prompt into a cache row.
+
+    The chunk's keys and values land at positions ``[start, length)``;
+    padding rows write nothing, so the row beyond the prompt stays as it
+    was.  Positions before ``start`` are read from ``cache`` as they are
+    (computed by earlier chunks, or gathered from shared prefix pages)."""
+    w = x.shape[1]
+    h = layers.rms_norm(params["ln1"], x, cfg.norm_eps)
+    rope_pos = positions[None, :] if cfg.pos_emb == "rope" else None
+    k, v = attn.gqa_project_kv(params["attn"], cfg, h, rope_pos)
+    key_pos = jnp.arange(cache["k"].shape[1])
+    rows = jnp.clip(key_pos - positions[0], 0, w - 1)
+    fresh = ((key_pos >= positions[0]) & (key_pos < length))[None, :, None, None]
+
+    def merge(old: jax.Array, new: jax.Array) -> jax.Array:
+        # the round trip of old values through the cache dtype is exact:
+        # they were computed in ``new``'s dtype
+        return jnp.where(fresh, jnp.take(new, rows, axis=1), old.astype(new.dtype))
+
+    k_ctx, v_ctx = merge(cache["k"], k), merge(cache["v"], v)
+    a = attn.gqa_chunk(params["attn"], cfg, h, k_ctx, v_ctx, positions)
+    x, _ = _tblock_ffn(params, cfg, x + a)
+    cache = {
+        "k": k_ctx.astype(cache["k"].dtype),
+        "v": v_ctx.astype(cache["v"].dtype),
+    }
+    return x, cache
 
 
 def tblock_decode(
@@ -349,16 +370,9 @@ class TransformerLM:
         cache: dict,
         *,
         dtype: Any = jnp.bfloat16,
-        start: int = 0,
     ) -> tuple[jax.Array, dict]:
-        """Run the prompt, fill the cache, return last-position logits.
-
-        ``start`` > 0 runs *suffix prefill*: ``batch["tokens"]`` holds only
-        the prompt suffix from position ``start`` on, and ``cache`` must
-        already hold the first ``start`` positions' KV."""
+        """Run the prompt, fill the cache, return last-position logits."""
         cfg = self.cfg
-        if start and cfg.family == "vlm":
-            raise ValueError("suffix prefill is not supported for VLM prompts")
         x, prefix_len = self._embed_inputs(params, batch, dtype)
         new_cache: dict = {}
 
@@ -367,7 +381,7 @@ class TransformerLM:
                 p_layer, c_layer = pc
                 h, c_layer, _ = tblock_prefill(
                     p_layer, cfg, h, c_layer, prefix_len=prefix_len,
-                    impl=self.attn_impl, start=start,
+                    impl=self.attn_impl,
                 )
                 return h, c_layer
 
@@ -383,6 +397,52 @@ class TransformerLM:
         x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
         logits = self._unembed(params, x[:, -1:], dtype)
         return logits[:, 0], new_cache
+
+    def prefill_chunk(
+        self,
+        params: dict,
+        tokens: jax.Array,  # (B, W) prompt positions start .. start+W
+        cache: dict,
+        start: jax.Array,   # () int32
+        length: jax.Array,  # () int32 prompt length
+        *,
+        dtype: Any = jnp.bfloat16,
+    ) -> tuple[jax.Array, dict]:
+        """Prefill one fixed-width chunk of a text prompt (GQA only) into
+        a cache row whose positions before ``start`` are already filled;
+        return the logits of position ``length - 1`` (meaningful when the
+        chunk holds it) and the row.
+
+        ``start`` and ``length`` are traced, so one program serves every
+        chunk of every prompt, whether it starts at 0 or after a shared
+        prefix: each position's KV is computed by the same program from
+        the same inputs either way (DESIGN.md §8)."""
+        cfg = self.cfg
+        if cfg.use_mla or cfg.family == "vlm":
+            raise ValueError(f"chunk prefill needs GQA text prompts: {cfg.name}")
+        w = tokens.shape[1]
+        positions = start + jnp.arange(w, dtype=jnp.int32)
+        x = self._embed_tokens(params, tokens, dtype)
+        new_cache: dict = {}
+
+        for stack_key in ("dense_layers", "moe_layers"):
+            if stack_key not in params:
+                continue
+
+            def scan_body(h, pc):
+                p_layer, c_layer = pc
+                return tblock_prefill_chunk(
+                    p_layer, cfg, h, c_layer, positions, length
+                )
+
+            x, new_cache[stack_key] = maybe_scan(
+                scan_body, x, (params[stack_key], cache[stack_key])
+            )
+
+        last = jnp.clip(length - 1 - start, 0, w - 1)
+        x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+        x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
+        return self._unembed(params, x, dtype)[:, 0], new_cache
 
     def decode_step(
         self,
@@ -971,3 +1031,27 @@ def active_param_count(cfg: ModelConfig, specs: PyTree) -> int:
 
     map_with_path(visit, specs)
     return total
+
+
+#: randomly initialized leaves that some forward path reads at float32 — the
+#: MoE router logits, the SSM depthwise conv, MLA's absorbed decode matmuls —
+#: so bf16 storage would change what the model computes
+_F32_READ_LEAVES = ("router", "conv_w", "wkv_b")
+
+
+def serving_param_specs(specs: PyTree) -> PyTree:
+    """Parameter specs at serving precision.
+
+    Every randomly initialized leaf that the forward casts to the compute
+    dtype before use (dense kernels, embedding tables, expert stacks) is
+    stored at bf16: the cast then happens once at init instead of in
+    every step, served tokens are unchanged, and weight memory halves.
+    Ones/zeros leaves (norm scales, biases, SSM constants) and the f32-read
+    leaves above keep their declared dtype."""
+
+    def cast(path: tuple[str, ...], s: ParamSpec) -> ParamSpec:
+        if s.init != "normal" or any(p in _F32_READ_LEAVES for p in path):
+            return s
+        return dataclasses.replace(s, dtype=jnp.bfloat16)
+
+    return map_with_path(cast, specs)

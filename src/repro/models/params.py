@@ -16,6 +16,7 @@ model definition from physical placement.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable
 
@@ -54,7 +55,11 @@ def is_spec(x: Any) -> bool:
     return isinstance(x, ParamSpec)
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def _init_one(key: jax.Array, spec: ParamSpec) -> jax.Array:
+    # jitted per leaf so the draw, scale and cast fuse: a bf16 leaf never
+    # materializes a full-size f32 temporary, which is what lets a
+    # full-width model initialize inside device memory
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
@@ -64,12 +69,21 @@ def _init_one(key: jax.Array, spec: ParamSpec) -> jax.Array:
         # convention (kernels are stored (in, out)).
         fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
         std = spec.scale / math.sqrt(max(fan_in, 1))
-        return (std * jax.random.normal(key, spec.shape)).astype(spec.dtype)
+        # the no-op f32 rounding stops XLA from folding ``std`` into the
+        # normal draw's own sqrt(2) factor, which would round differently
+        # from the unfused ``std * normal`` these values are defined by
+        draw = jax.lax.reduce_precision(
+            jax.random.normal(key, spec.shape), exponent_bits=8, mantissa_bits=23
+        )
+        return (std * draw).astype(spec.dtype)
     raise ValueError(f"unknown init {spec.init!r}")
 
 
 def init_params(key: jax.Array, specs: PyTree) -> PyTree:
-    """Materialize real parameters for a spec tree (small configs only)."""
+    """Materialize real parameters for a spec tree.
+
+    Arrays land on the default device; callers place a replica's params
+    by initializing under ``jax.default_device``."""
     leaves, treedef = jax.tree.flatten(specs, is_leaf=is_spec)
     keys = jax.random.split(key, len(leaves))
     arrays = [_init_one(k, s) for k, s in zip(keys, leaves)]

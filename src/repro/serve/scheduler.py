@@ -8,17 +8,22 @@ jitted step, so slots are the unit of multiplexing instead).  Each iteration:
 2. free slots are refilled from the request queue (admission-controlled),
 3. a single batched decode step advances every active slot by one token.
 
-Prefill is **exact-length**: each distinct prompt length compiles one
-prefill program (``prefill_recompiles`` counts them).  Right-padding to
-power-of-two buckets would bound recompiles for attention caches (padding
-is never attended) but corrupts SSM recurrent state, so callers that need
-bounded compiles bucket prompt lengths at the data layer instead.
+GQA text models (dense and MoE families) prefill through **one
+fixed-width chunk program**: a prompt runs in chunks of ``PREFILL_WIDTH``
+rows, zero-padded, each attending to the whole cache row up to its own
+positions.  Its shapes never depend on the prompt, so it compiles once and
+every position's KV comes out of the same program whether prefill started
+at 0 or after a shared prefix — on a TPU, whose compiler rounds programs
+of different shapes differently, that is what keeps paged and contiguous
+serving byte-identical.  The other families prefill **exact-length**: one
+program per distinct prompt length, since padding would corrupt SSM
+recurrent state.  ``prefill_recompiles`` counts the programs.
 
 With ``page_size`` > 0 the KV cache is **paged** (DESIGN.md §8): device
 leaves become page pools, each slot holds a page table, and a host-side
 :class:`~repro.serve.paged_cache.PagedCacheManager` shares prompt-prefix
 pages across requests by hash chain — a prompt whose leading pages are
-resident skips prefill for them (suffix prefill picks up at the first
+resident skips prefill for them (its chunks start at the first
 non-shared token).  Decode gathers each slot's pages into the contiguous
 view the decode step already understands, then scatters the one new KV
 row back to its pool page.
@@ -50,8 +55,15 @@ from repro.sharding import ShardingRules, use_rules
 PyTree = Any
 
 #: model families whose caches are pure attention KV (batch x seq leaves)
-#: and whose prefill supports the suffix ``start`` offset
+#: and whose prefill can start after a shared prefix
 _PAGEABLE_FAMILIES = ("dense", "moe")
+
+#: rows of the chunk prefill program (capped at the batcher's ``max_len``)
+PREFILL_WIDTH = 128
+
+
+def _chunk_prefill_supported(cfg: ModelConfig) -> bool:
+    return cfg.family in _PAGEABLE_FAMILIES and not getattr(cfg, "use_mla", False)
 
 
 @dataclasses.dataclass
@@ -202,7 +214,7 @@ class ContinuousBatcher:
         self.page_size = page_size
         #: "bf16" = full-precision pool pages (the pre-quantization path);
         #: "int8" = absmax block-quantized pages + per-(page, head) scales,
-        #: dequantized in-kernel / at gather time (DESIGN.md §10)
+        #: dequantized at gather time (DESIGN.md §10)
         if kv_cache_dtype not in ("bf16", "int8"):
             raise ValueError(
                 f"kv_cache_dtype must be 'bf16' or 'int8', got "
@@ -219,9 +231,7 @@ class ContinuousBatcher:
         cache_specs = model.cache_specs(n_slots, max_len, cache_dtype)
         self._batch_axes = batch_axis_tree(cache_specs)
         if page_size:
-            if cfg.family not in _PAGEABLE_FAMILIES or getattr(
-                cfg, "use_mla", False
-            ):
+            if not _chunk_prefill_supported(cfg):
                 raise ValueError(
                     f"paged KV cache supports GQA attention families "
                     f"{_PAGEABLE_FAMILIES}, not {cfg.family}"
@@ -295,13 +305,18 @@ class ContinuousBatcher:
 
         self._decode_fn = steps_lib.make_decode_fn(model, cfg)
         self._decode = jax.jit(self._decode_fn)
-        if page_size:
+        #: GQA text models prefill through one fixed-width chunk program
+        #: (paged or not); the other families run one exact-length program
+        #: per prompt length
+        self._chunked = _chunk_prefill_supported(cfg)
+        if self._chunked:
+            self.prefill_width = min(PREFILL_WIDTH, max_len)
+            self._prefill_chunk = jax.jit(model.prefill_chunk)
+        else:
             self._prefill = jax.jit(
-                lambda params, batch, cache, start: model.prefill(
-                    params, batch, cache, start=start
-                ),
-                static_argnums=(3,),
+                lambda params, batch, cache: model.prefill(params, batch, cache)
             )
+        if page_size:
             if self.quantized:
                 self._paged_decode_q = jax.jit(self._paged_decode_q_impl)
                 self._read_prefix_q = jax.jit(self._read_prefix_q_impl)
@@ -316,10 +331,6 @@ class ContinuousBatcher:
                     self._write_pages_impl, static_argnums=(3,)
                 )
                 self._copy_page = jax.jit(self._copy_page_impl)
-        else:
-            self._prefill = jax.jit(
-                lambda params, batch, cache: model.prefill(params, batch, cache)
-            )
         self._insert = jax.jit(self._insert_impl)
 
         # slot state (host side)
@@ -339,8 +350,8 @@ class ContinuousBatcher:
         if page_size:
             self.stats.kv_bytes_per_token = self._page_bytes // page_size
             self.stats.pool_pages = self.manager.n_pages
-        #: prompt shapes already compiled: lengths in contiguous mode,
-        #: (shared_prefix, suffix_len) pairs in paged mode
+        #: prefill programs already compiled: the chunk width, or the prompt
+        #: lengths of exact-length prefill
         self._seen_prefill_shapes: set = set()
         #: deterministic chaos hook: called with ``steps_run`` at the top
         #: of every step(); may raise (replica_crash), sleep (slow_step),
@@ -633,19 +644,43 @@ class ContinuousBatcher:
             return jax.default_device(self.device)
         return contextlib.nullcontext()
 
+    def _count_prefill_program(self, shape: Any) -> None:
+        if shape not in self._seen_prefill_shapes:
+            self._seen_prefill_shapes.add(shape)
+            self.stats.prefill_recompiles += 1
+
+    def _prefill_chunks(
+        self, row: PyTree, ptoks: list[int], start: int
+    ) -> tuple[jax.Array, PyTree]:
+        """Prefill prompt positions ``[start, len(ptoks))`` into ``row``
+        chunk by chunk; return the last position's logits and the row."""
+        n, w = len(ptoks), self.prefill_width
+        if n > self.max_len:
+            raise ValueError(f"prompt of {n} tokens exceeds max_len {self.max_len}")
+        self._count_prefill_program(w)
+        for c in range(start, n, w):
+            chunk = np.zeros((1, w), np.int32)
+            part = ptoks[c : c + w]
+            chunk[0, : len(part)] = part
+            logits, row = self._prefill_chunk(
+                self.params, jnp.asarray(chunk), row, np.int32(c), np.int32(n)
+            )
+        return logits, row
+
     def _contiguous_prefill(self, slot: int, req: Request) -> int:
         ptoks = req.prompt_tokens
-        if len(ptoks) not in self._seen_prefill_shapes:
-            self._seen_prefill_shapes.add(len(ptoks))
-            self.stats.prefill_recompiles += 1
-        batch = {"tokens": jnp.asarray(np.asarray(ptoks, np.int32)[None])}
-        if req.extras:
-            batch.update(
-                {k: jnp.asarray(v)[None] for k, v in req.extras.items()}
-            )
         with self._compute_ctx():
             row_cache = init_params(jax.random.key(1), self._row_specs)
-            logits, row_cache = self._prefill(self.params, batch, row_cache)
+            if self._chunked:
+                logits, row_cache = self._prefill_chunks(row_cache, ptoks, 0)
+            else:
+                self._count_prefill_program(len(ptoks))
+                batch = {"tokens": jnp.asarray(np.asarray(ptoks, np.int32)[None])}
+                if req.extras:
+                    batch.update(
+                        {k: jnp.asarray(v)[None] for k, v in req.extras.items()}
+                    )
+                logits, row_cache = self._prefill(self.params, batch, row_cache)
             self.cache = self._insert(self.cache, row_cache, slot)
             return int(
                 jax.device_get(
@@ -662,15 +697,6 @@ class ContinuousBatcher:
         start = match.n_shared_tokens
         self.stats.prefix_pages_hit += match.n_shared_pages
         self.stats.prefix_tokens_saved += start
-        if (start, len(ptoks) - start) not in self._seen_prefill_shapes:
-            self._seen_prefill_shapes.add((start, len(ptoks) - start))
-            self.stats.prefill_recompiles += 1
-        suffix = np.asarray(ptoks[start:], np.int32)[None]
-        batch = {"tokens": jnp.asarray(suffix)}
-        if req.extras:
-            batch.update(
-                {k: jnp.asarray(v)[None] for k, v in req.extras.items()}
-            )
         with self._compute_ctx():
             row = init_params(jax.random.key(1), self._row_specs)
             if match.n_shared_pages:
@@ -683,7 +709,7 @@ class ContinuousBatcher:
                     )
                 else:
                     row = self._read_prefix(row, self.cache, shared)
-            logits, row = self._prefill(self.params, batch, row, start)
+            logits, row = self._prefill_chunks(row, ptoks, start)
             fresh = jnp.asarray(
                 match.page_ids[match.n_shared_pages :], jnp.int32
             )

@@ -97,3 +97,57 @@ def test_ragged_positions_decode(rng):
         )
         outs.append(np.asarray(lg2)[0])
     assert all(np.all(np.isfinite(o)) for o in outs)
+
+
+def _chunk_prefill(model, params, toks, cache, start, width):
+    """Run prompt positions [start, len(toks)) through ``prefill_chunk``."""
+    n = len(toks)
+    for c in range(start, n, width):
+        chunk = np.zeros((1, width), np.int32)
+        part = toks[c : c + width]
+        chunk[0, : len(part)] = part
+        lg, cache = model.prefill_chunk(
+            params, jnp.asarray(chunk), cache, jnp.int32(c), jnp.int32(n),
+            dtype=jnp.float32,
+        )
+    return lg, cache
+
+
+@pytest.mark.parametrize("arch", ["qwen3-4b", "qwen3-moe-30b-a3b"])
+def test_chunk_prefill_matches_exact_prefill(arch, rng):
+    """Fixed-width chunks (three of them, the last one padded) fill the
+    same cache and give the same last-position logits as one exact-length
+    prefill; positions past the prompt stay empty."""
+    cfg, model, params, _, _, _ = _setup(arch, rng)
+    toks = rng.randint(3, cfg.vocab_size, (20,)).tolist()
+    specs = model.cache_specs(1, MAX + 8, jnp.float32)
+    want, want_cache = model.prefill(
+        params, {"tokens": jnp.asarray([toks], jnp.int32)},
+        pm.init_params(jax.random.key(1), specs), dtype=jnp.float32,
+    )
+    got, got_cache = _chunk_prefill(
+        model, params, toks, pm.init_params(jax.random.key(1), specs), 0, 8
+    )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5)
+    for g, w in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
+        g, w = np.asarray(g), np.asarray(w)
+        np.testing.assert_allclose(g[:, :, :20], w[:, :, :20], atol=5e-5)
+        assert not g[:, :, 20:].any()
+
+
+def test_chunk_prefill_after_a_prefix_is_bitwise_identical(rng):
+    """Prefill that starts after a cached prefix (as paged serving does
+    after shared pages) runs the same program as prefill from position 0,
+    so it reproduces the logits and cache bit for bit even though its
+    chunks start elsewhere."""
+    cfg, model, params, _, _, _ = _setup("qwen3-4b", rng)
+    toks = rng.randint(3, cfg.vocab_size, (20,)).tolist()
+    empty = pm.init_params(
+        jax.random.key(1), model.cache_specs(1, MAX + 8, jnp.float32)
+    )
+    full_lg, full = _chunk_prefill(model, params, toks, empty, 0, 8)
+    prefix = jax.tree.map(lambda c, f: c.at[:, :, :12].set(f[:, :, :12]), empty, full)
+    lg, row = _chunk_prefill(model, params, toks, prefix, 12, 8)
+    assert np.asarray(lg).tobytes() == np.asarray(full_lg).tobytes()
+    for a, b in zip(jax.tree.leaves(row), jax.tree.leaves(full)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()

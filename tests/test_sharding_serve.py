@@ -79,8 +79,8 @@ def test_batch_axis_tree():
 # ---------------------------------------------------------------------------
 
 
-def _scheduler(n_slots=3, **kw):
-    cfg = ARCHS["qwen3-4b"].reduced()
+def _scheduler(n_slots=3, arch="qwen3-4b", **kw):
+    cfg = ARCHS[arch].reduced()
     model = build_model(cfg, remat="none")
     params = pm.init_params(jax.random.key(0), model.param_specs())
     return ContinuousBatcher(
@@ -116,7 +116,7 @@ def test_scheduler_occupancy_counters(rng):
     prompt-length recompile tracking, incremental completion draining."""
     sched, _ = _scheduler(n_slots=2)
     for i in range(5):
-        # two distinct prompt lengths -> exactly 2 prefill recompiles
+        # two distinct prompt lengths share the one chunk prefill program
         sched.submit(
             Request(i, prompt_tokens=[5, 6, 7][: 2 + i % 2], max_new_tokens=4)
         )
@@ -132,7 +132,7 @@ def test_scheduler_occupancy_counters(rng):
     st = sched.stats
     assert st.admissions == 5
     assert st.completions >= 5
-    assert st.prefill_recompiles == 2
+    assert st.prefill_recompiles == 1
     assert st.steps == sched.steps_run
     assert 0.0 < st.occupancy <= 1.0
     assert 0.0 < st.tokens_per_step <= 2.0
@@ -140,6 +140,19 @@ def test_scheduler_occupancy_counters(rng):
     d = st.as_dict()
     assert d["n_slots"] == 2 and d["admissions"] == 5
     assert sched.completions == []  # drained incrementally
+
+
+def test_exact_length_prefill_counts_one_program_per_length():
+    """An SSM cannot take padded prompts, so it compiles one prefill
+    program per distinct prompt length."""
+    sched, _ = _scheduler(n_slots=2, arch="mamba2-2.7b")
+    assert not sched._chunked
+    for i in range(4):
+        sched.submit(
+            Request(i, prompt_tokens=[5, 6, 7][: 2 + i % 2], max_new_tokens=2)
+        )
+    assert len(sched.run_to_completion()) == 4
+    assert sched.stats.prefill_recompiles == 2
 
 
 def test_scheduler_greedy_deterministic(rng):
