@@ -7,6 +7,7 @@ import threading
 import time
 from typing import Any
 
+from repro import obs
 from repro.core.config import CachePolicy, cache_key
 from repro.storage.deltalite import DeltaLite
 
@@ -80,6 +81,10 @@ class ResponseCache:
     def lookup(self, key: str) -> CacheEntry | None:
         if self.policy in (CachePolicy.DISABLED, CachePolicy.WRITE_ONLY):
             return None
+        with obs.span("cache.lookup"):
+            return self._lookup(key)
+
+    def _lookup(self, key: str) -> CacheEntry | None:
         with self._lock:
             self._refresh()
             if key not in self._keys:

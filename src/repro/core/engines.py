@@ -137,6 +137,13 @@ class BatcherStats:
     kv_bytes_per_token: int = 0
     #: KV page-pool size in pages (0 on contiguous-cache batchers)
     pool_pages: int = 0
+    #: seconds requests spent queued before admission, summed over
+    #: admissions (a preempted request counts from its first queue entry),
+    #: and seconds from admission until the first token reached the host
+    #: (prefill, page movement and the first sample's sync); both are added
+    #: at admission
+    queue_wait_s: float = 0.0
+    first_token_s: float = 0.0
 
     @property
     def tokens_per_step(self) -> float:
@@ -166,6 +173,8 @@ class BatcherStats:
             "preempted_tokens": self.preempted_tokens,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "pool_pages": self.pool_pages,
+            "queue_wait_s": self.queue_wait_s,
+            "first_token_s": self.first_token_s,
         }
 
 
@@ -368,6 +377,8 @@ class SimulatedSlotEngine(InferenceEngine):
     :class:`~repro.core.service.InferenceService` batcher loop drives.
     Output lengths are long-tail skewed on purpose: that is the regime
     where lock-step waves pay the straggler price every time.
+    Its ``queue_wait_s`` and ``first_token_s`` stay 0: it keeps no
+    wall-clock stamps of admission.
     """
 
     supports_streaming = True

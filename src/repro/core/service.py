@@ -58,6 +58,7 @@ import threading
 import time
 from typing import Any, Callable, Sequence
 
+from repro import obs
 from repro.core.engines import (
     InferenceEngine,
     InferenceRequest,
@@ -349,6 +350,7 @@ def aggregate_batcher_stats(parts: Sequence[dict]) -> dict:
             "tokens_generated", "active_slot_steps", "prefill_recompiles",
             "prefills_deferred", "prefix_pages_hit", "prefix_tokens_saved",
             "cow_copies", "preemptions", "preempted_tokens", "pool_pages",
+            "queue_wait_s", "first_token_s",
         )
     }
     # a rate, not a counter: replicas of one config share it, so take max
@@ -821,7 +823,10 @@ class InferenceService:
                 admit_rr += 1
                 sub.flight.attempts += 1
                 self._count_dispatch(rep)
-                pending[engine.stream_submit(sub.request)] = sub
+                with obs.span("service.dispatch") as sp:
+                    rid = engine.stream_submit(sub.request)
+                    sp.set(rid=rid)
+                pending[rid] = sub
             except RecoverableEngineError as e:
                 # transient refusal: burn a backoff slot, not the replica
                 if sub.flight.attempts <= sub.max_retries:
@@ -911,7 +916,8 @@ class InferenceService:
                 # (injected sleep — a no-op under virtual clocks)
                 self._sleep(wait_s)
                 continue
-            done = engine.stream_pump()
+            with obs.span("service.pump"):
+                done = engine.stream_pump()
             for rid, resp in done:
                 sub2 = pending.pop(rid, None)
                 if sub2 is None:
@@ -928,7 +934,8 @@ class InferenceService:
                     )
                     retry_at.append((time.monotonic() + delay, sub2))
                     continue
-                self._resolve(sub2, resp)
+                with obs.span("service.resolve", rid=rid):
+                    self._resolve(sub2, resp)
             # health probe: progress = completions delivered or engine
             # decode steps advancing; a loaded replica that shows neither
             # for health_probe_steps consecutive pumps is hung (a wedged

@@ -55,6 +55,7 @@ import json
 import time
 from typing import Any, Iterable, Iterator
 
+from repro import obs
 from repro.core.config import EvalTask, StatisticsConfig
 from repro.core.stages import (
     EvalArtifact,
@@ -309,9 +310,9 @@ class StreamingPipeline:
             chunk_states: dict[str, dict] = {}
             chunk_timing: dict[str, float] = {}
             for stage in stages:
-                t0 = time.monotonic()
-                art = stage.run(art, session)
-                chunk_timing[f"{stage.name}_s"] = time.monotonic() - t0
+                with obs.timed(f"stage.{stage.name}") as sp:
+                    art = stage.run(art, session)
+                chunk_timing[f"{stage.name}_s"] = sp.seconds
             for key, dt in chunk_timing.items():
                 timing[key] = timing.get(key, 0.0) + dt
 
@@ -685,9 +686,9 @@ class ConcurrentStreamingExecutor:
         art = EvalArtifact(rows=chunk, task=task)
         chunk_timing: dict[str, float] = {}
         for stage in stages:
-            t0 = time.monotonic()
-            art = stage.run(art, session)
-            chunk_timing[f"{stage.name}_s"] = time.monotonic() - t0
+            with obs.timed(f"stage.{stage.name}") as sp:
+                art = stage.run(art, session)
+            chunk_timing[f"{stage.name}_s"] = sp.seconds
 
         accs: dict[str, MetricAccumulator] = {}
         chunk_engine: BootstrapEngine | None = None

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.engines import BatcherStats
 from repro.kernels.decode_attention.quant import absmax_quantize
@@ -72,6 +73,12 @@ class Request:
     prompt_tokens: list[int]
     max_new_tokens: int = 32
     extras: dict | None = None  # e.g. {"frames": ...} for enc-dec archs
+    #: ``time.monotonic()`` stamps: first queue entry (``submit``; a
+    #: preempted request keeps it), admission into a slot, and the moment
+    #: its first token reached the host
+    queued_at: float = 0.0
+    admitted_at: float = 0.0
+    first_token_at: float = 0.0
 
 
 @dataclasses.dataclass
@@ -80,7 +87,16 @@ class Completion:
     tokens: list[int]
     prompt_len: int
     finished_reason: str  # "eos" | "length" | "truncated"
-    latency_s: float = 0.0
+    #: the request's stamps, and when it finished
+    queued_at: float = 0.0
+    admitted_at: float = 0.0
+    first_token_at: float = 0.0
+    finished_at: float = 0.0
+
+    @property
+    def latency_s(self) -> float:
+        """Queue entry to finish."""
+        return self.finished_at - self.queued_at
 
 
 def batch_axis_tree(cache_specs: PyTree) -> PyTree:
@@ -338,7 +354,6 @@ class ContinuousBatcher:
         self.slot_req: list[Request | None] = [None] * n_slots
         self.slot_tokens: list[list[int]] = [[] for _ in range(n_slots)]
         self.slot_pos = np.zeros((n_slots,), np.int32)  # next position to write
-        self.slot_started = np.zeros((n_slots,), np.float64)
         self.cur_tokens = np.zeros((n_slots, 1), np.int32)
         self.queue: list[Request] = []
         self.completions: list[Completion] = []
@@ -614,6 +629,7 @@ class ContinuousBatcher:
     # -- public API --------------------------------------------------------------
 
     def submit(self, req: Request) -> None:
+        req.queued_at = time.monotonic()
         self.queue.append(req)
 
     @property
@@ -658,19 +674,32 @@ class ContinuousBatcher:
         if n > self.max_len:
             raise ValueError(f"prompt of {n} tokens exceeds max_len {self.max_len}")
         self._count_prefill_program(w)
-        for c in range(start, n, w):
-            chunk = np.zeros((1, w), np.int32)
-            part = ptoks[c : c + w]
-            chunk[0, : len(part)] = part
-            logits, row = self._prefill_chunk(
-                self.params, jnp.asarray(chunk), row, np.int32(c), np.int32(n)
-            )
+        with obs.span("sched.prefill", tokens=n - start):
+            for c in range(start, n, w):
+                chunk = np.zeros((1, w), np.int32)
+                part = ptoks[c : c + w]
+                chunk[0, : len(part)] = part
+                logits, row = self._prefill_chunk(
+                    self.params, jnp.asarray(chunk), row, np.int32(c),
+                    np.int32(n),
+                )
         return logits, row
+
+    def _first_token(self, logits: jax.Array) -> int:
+        """Bring the prefill's greedy token to the host (waits for the
+        admission's device work)."""
+        with obs.span("sched.first_token"):
+            return int(
+                jax.device_get(
+                    steps_lib.greedy_sample(logits, self.cfg.vocab_size)
+                )[0]
+            )
 
     def _contiguous_prefill(self, slot: int, req: Request) -> int:
         ptoks = req.prompt_tokens
         with self._compute_ctx():
-            row_cache = init_params(jax.random.key(1), self._row_specs)
+            with obs.span("sched.row_init"):
+                row_cache = init_params(jax.random.key(1), self._row_specs)
             if self._chunked:
                 logits, row_cache = self._prefill_chunks(row_cache, ptoks, 0)
             else:
@@ -680,13 +709,10 @@ class ContinuousBatcher:
                     batch.update(
                         {k: jnp.asarray(v)[None] for k, v in req.extras.items()}
                     )
-                logits, row_cache = self._prefill(self.params, batch, row_cache)
+                with obs.span("sched.prefill", tokens=len(ptoks)):
+                    logits, row_cache = self._prefill(self.params, batch, row_cache)
             self.cache = self._insert(self.cache, row_cache, slot)
-            return int(
-                jax.device_get(
-                    steps_lib.greedy_sample(logits, self.cfg.vocab_size)
-                )[0]
-            )
+            return self._first_token(logits)
 
     def _paged_prefill(self, slot: int, req: Request) -> int:
         """Acquire pages (reusing any resident shared prefix), prefill only
@@ -698,35 +724,35 @@ class ContinuousBatcher:
         self.stats.prefix_pages_hit += match.n_shared_pages
         self.stats.prefix_tokens_saved += start
         with self._compute_ctx():
-            row = init_params(jax.random.key(1), self._row_specs)
+            with obs.span("sched.row_init"):
+                row = init_params(jax.random.key(1), self._row_specs)
             if match.n_shared_pages:
-                shared = jnp.asarray(
-                    match.page_ids[: match.n_shared_pages], jnp.int32
+                with obs.span("sched.prefix_read", pages=match.n_shared_pages):
+                    shared = jnp.asarray(
+                        match.page_ids[: match.n_shared_pages], jnp.int32
+                    )
+                    if self.quantized:
+                        row = self._read_prefix_q(
+                            row, self.cache, self.scales, shared
+                        )
+                    else:
+                        row = self._read_prefix(row, self.cache, shared)
+            logits, row = self._prefill_chunks(row, ptoks, start)
+            n_fresh = len(match.page_ids) - match.n_shared_pages
+            with obs.span("sched.page_write", pages=n_fresh):
+                fresh = jnp.asarray(
+                    match.page_ids[match.n_shared_pages :], jnp.int32
                 )
                 if self.quantized:
-                    row = self._read_prefix_q(
-                        row, self.cache, self.scales, shared
+                    self.cache, self.scales = self._write_pages_q(
+                        self.cache, self.scales, row, fresh,
+                        match.n_shared_pages, len(ptoks) - start,
                     )
                 else:
-                    row = self._read_prefix(row, self.cache, shared)
-            logits, row = self._prefill_chunks(row, ptoks, start)
-            fresh = jnp.asarray(
-                match.page_ids[match.n_shared_pages :], jnp.int32
-            )
-            if self.quantized:
-                self.cache, self.scales = self._write_pages_q(
-                    self.cache, self.scales, row, fresh,
-                    match.n_shared_pages, len(ptoks) - start,
-                )
-            else:
-                self.cache = self._write_pages(
-                    self.cache, row, fresh, match.n_shared_pages
-                )
-            first_tok = int(
-                jax.device_get(
-                    steps_lib.greedy_sample(logits, self.cfg.vocab_size)
-                )[0]
-            )
+                    self.cache = self._write_pages(
+                        self.cache, row, fresh, match.n_shared_pages
+                    )
+            first_tok = self._first_token(logits)
         self.manager.register(slot, ptoks)
         return first_tok
 
@@ -770,19 +796,23 @@ class ContinuousBatcher:
                 break
             req = self.queue.pop(0)
             self._admit(req)
+            req.admitted_at = time.monotonic()
             ptoks = req.prompt_tokens
             self.stats.admissions += 1
-            if self.page_size:
-                first_tok = self._paged_prefill(slot, req)
-            else:
-                first_tok = self._contiguous_prefill(slot, req)
+            with obs.span("sched.admit", rid=req.request_id):
+                if self.page_size:
+                    first_tok = self._paged_prefill(slot, req)
+                else:
+                    first_tok = self._contiguous_prefill(slot, req)
+            req.first_token_at = time.monotonic()
+            self.stats.queue_wait_s += req.admitted_at - req.queued_at
+            self.stats.first_token_s += req.first_token_at - req.admitted_at
             admitted += 1
 
             self.slot_free[slot] = False
             self.slot_req[slot] = req
             self.slot_tokens[slot] = [first_tok]
             self.slot_pos[slot] = self.prefix + len(ptoks)
-            self.slot_started[slot] = time.monotonic()
             self.cur_tokens[slot, 0] = first_tok
 
     def _finish(self, slot: int, reason: str) -> None:
@@ -794,7 +824,10 @@ class ContinuousBatcher:
                 tokens=list(self.slot_tokens[slot]),
                 prompt_len=len(req.prompt_tokens),
                 finished_reason=reason,
-                latency_s=time.monotonic() - self.slot_started[slot],
+                queued_at=req.queued_at,
+                admitted_at=req.admitted_at,
+                first_token_at=req.first_token_at,
+                finished_at=time.monotonic(),
             )
         )
         self._release_slot(slot)
@@ -891,6 +924,10 @@ class ContinuousBatcher:
 
     def step(self) -> int:
         """One scheduler iteration; returns number of active slots stepped."""
+        with obs.span("sched.step"):
+            return self._step()
+
+    def _step(self) -> int:
         if self.fault_hook is not None:
             kind = self.fault_hook(self.steps_run)
             if kind == "page_pressure":
@@ -900,9 +937,12 @@ class ContinuousBatcher:
         # finish-check *before* refill so a slot freed by the previous
         # iteration's sample is refillable in this very step, then check
         # again for fresh slots whose first token already terminated them
-        self._reap()
-        self._refill()
-        self._reap()
+        with obs.span("sched.reap"):
+            self._reap()
+        with obs.span("sched.refill"):
+            self._refill()
+        with obs.span("sched.reap"):
+            self._reap()
         active = [s for s in range(self.n_slots) if not self.slot_free[s]]
         if not active:
             return 0
@@ -913,51 +953,57 @@ class ContinuousBatcher:
                 # and retries instead of killing the replica (DESIGN.md §9);
                 # ensure_position is idempotent, so rebuilding the tables
                 # after a preemption released pages is safe
-                while True:
-                    try:
-                        tables, wpages, woffs = self._paged_step_tables(active)
-                        break
-                    except PagePoolExhausted:
-                        self._preempt_victim()
-                        active = [
-                            s for s in range(self.n_slots)
-                            if not self.slot_free[s]
-                        ]
-                        if not active:
-                            return 0
+                with obs.span("sched.decode.tables"):
+                    while True:
+                        try:
+                            tables, wpages, woffs = self._paged_step_tables(
+                                active
+                            )
+                            break
+                        except PagePoolExhausted:
+                            self._preempt_victim()
+                            active = [
+                                s for s in range(self.n_slots)
+                                if not self.slot_free[s]
+                            ]
+                            if not active:
+                                return 0
             self.stats.steps += 1
             self.stats.active_slot_steps += len(active)
             self.stats.tokens_generated += len(active)
-            tokens = jnp.asarray(self.cur_tokens)
-            positions = jnp.asarray(self.slot_pos)
-            if self.page_size and self.quantized:
-                logits, self.cache, self.scales = self._paged_decode_q(
-                    self.params, tokens, self.cache, self.scales,
-                    jnp.asarray(tables), positions,
-                    jnp.asarray(wpages), jnp.asarray(woffs),
-                )
-            elif self.page_size:
-                logits, self.cache = self._paged_decode(
-                    self.params, tokens, self.cache, jnp.asarray(tables),
-                    positions, jnp.asarray(wpages), jnp.asarray(woffs),
-                )
-            else:
-                logits, self.cache = self._decode(
-                    self.params, tokens, self.cache, positions
-                )
-            if self.temperature > 0:
-                self.key, sub = jax.random.split(self.key)
-                nxt = steps_lib.temperature_sample(
-                    logits, self.cfg.vocab_size, self.temperature, sub
-                )
-            else:
-                nxt = steps_lib.greedy_sample(logits, self.cfg.vocab_size)
-            nxt = np.asarray(jax.device_get(nxt))
+            with obs.span("sched.decode.dispatch", slots=len(active)):
+                tokens = jnp.asarray(self.cur_tokens)
+                positions = jnp.asarray(self.slot_pos)
+                if self.page_size and self.quantized:
+                    logits, self.cache, self.scales = self._paged_decode_q(
+                        self.params, tokens, self.cache, self.scales,
+                        jnp.asarray(tables), positions,
+                        jnp.asarray(wpages), jnp.asarray(woffs),
+                    )
+                elif self.page_size:
+                    logits, self.cache = self._paged_decode(
+                        self.params, tokens, self.cache, jnp.asarray(tables),
+                        positions, jnp.asarray(wpages), jnp.asarray(woffs),
+                    )
+                else:
+                    logits, self.cache = self._decode(
+                        self.params, tokens, self.cache, positions
+                    )
+                if self.temperature > 0:
+                    self.key, sub = jax.random.split(self.key)
+                    nxt = steps_lib.temperature_sample(
+                        logits, self.cfg.vocab_size, self.temperature, sub
+                    )
+                else:
+                    nxt = steps_lib.greedy_sample(logits, self.cfg.vocab_size)
+            with obs.span("sched.decode.sync"):
+                nxt = np.asarray(jax.device_get(nxt))
 
-        for slot in active:
-            self.slot_tokens[slot].append(int(nxt[slot]))
-            self.slot_pos[slot] += 1
-            self.cur_tokens[slot, 0] = int(nxt[slot])
+        with obs.span("sched.decode.commit"):
+            for slot in active:
+                self.slot_tokens[slot].append(int(nxt[slot]))
+                self.slot_pos[slot] += 1
+                self.cur_tokens[slot, 0] = int(nxt[slot])
         self.steps_run += 1
         return len(active)
 

@@ -30,6 +30,8 @@ import time
 import uuid
 from typing import Iterable
 
+from repro import obs
+
 
 class CommitConflict(Exception):
     """Another writer committed this version first; retry."""
@@ -78,11 +80,13 @@ class DeltaLite:
         return v
 
     def _read_log(self, version: int | None = None) -> list[dict]:
-        last = self.latest_version() if version is None else version
-        entries = []
-        for v in range(last + 1):
-            with open(self._version_path(v)) as f:
-                entries.append(json.load(f))
+        with obs.span("storage.read_log") as sp:
+            last = self.latest_version() if version is None else version
+            entries = []
+            for v in range(last + 1):
+                with open(self._version_path(v)) as f:
+                    entries.append(json.load(f))
+            sp.set(files=len(entries))
         return entries
 
     def _live_segments(self, version: int | None = None) -> list[dict]:
@@ -206,7 +210,7 @@ class DeltaLite:
 
     def _read_segment(self, name: str) -> list[dict]:
         fpath = os.path.join(self.path, "data", name)
-        with gzip.open(fpath, "rt") as f:
+        with obs.span("storage.read_segment"), gzip.open(fpath, "rt") as f:
             return [json.loads(line) for line in f if line.strip()]
 
     def read(self, version: int | None = None) -> list[dict]:
