@@ -40,34 +40,23 @@ class CacheEntry:
 class ResponseCache:
     """Five-policy cache; point lookups go through the DeltaLite CAS index.
 
-    A warm in-memory key set makes the hot path O(1); it is rebuilt lazily
-    from the log when the underlying table version moves (other writers).
+    The table handle keeps the committed log in memory, key index included,
+    and folds in other writers' commits on the next read, so a lookup costs
+    one version check and the read of the segment that holds the key.
 
-    One handle is safely shared by concurrent chunk workers: the key set,
-    version watermark and hit/miss/write counters are guarded by a single
-    reentrant lock, so a ``_refresh`` racing a ``put`` can never publish a
-    key set older than the version it is stamped with, and the counters
-    never lose increments.  (DeltaLite appends themselves are already safe
-    via optimistic concurrency — the lock covers the in-memory mirror.)
+    One handle is safely shared by concurrent chunk workers: the
+    hit/miss/write counters are guarded by a lock, so they never lose
+    increments.  (DeltaLite guards its own snapshot, and appends are safe
+    via optimistic concurrency.)
     """
 
     def __init__(self, path: str, policy: CachePolicy = CachePolicy.ENABLED):
         self.policy = policy
         self.table = DeltaLite(path, key_column="prompt_hash")
-        self._known_version = -2
-        self._keys: set[str] = set()
-        self._lock = threading.RLock()
+        self._lock = threading.Lock()
         self.hits = 0
         self.misses = 0
         self.writes = 0
-
-    # -- key management --------------------------------------------------------
-
-    def _refresh(self) -> None:
-        v = self.table.latest_version()
-        if v != self._known_version:
-            self._keys = self.table.keys() if v >= 0 else set()
-            self._known_version = v
 
     @staticmethod
     def key_for(
@@ -85,20 +74,15 @@ class ResponseCache:
             return self._lookup(key)
 
     def _lookup(self, key: str) -> CacheEntry | None:
-        with self._lock:
-            self._refresh()
-            if key not in self._keys:
-                if self.policy == CachePolicy.REPLAY:
-                    raise CacheMiss(
-                        f"replay mode: {key[:12]}… not cached "
-                        f"({len(self._keys)} entries present)"
-                    )
-                self.misses += 1
-                return None
-        # segment read happens outside the lock: concurrent lookups must
-        # not serialize behind each other's (or a writer's) disk I/O
+        # no lock held: concurrent lookups must not serialize behind each
+        # other's (or a writer's) disk I/O
         row = self.table.lookup(key)
-        if row is None:  # pragma: no cover — index said yes, table says no
+        if row is None:
+            if self.policy == CachePolicy.REPLAY:
+                raise CacheMiss(
+                    f"replay mode: {key[:12]}… not cached "
+                    f"({len(self.table.keys())} entries present)"
+                )
             with self._lock:
                 self.misses += 1
             return None
@@ -121,16 +105,15 @@ class ResponseCache:
         if not entries:
             return 0
         # the append itself is already safe under DeltaLite's optimistic
-        # concurrency; only the in-memory mirror goes under the lock, so
-        # readers are never blocked behind a writer's segment+commit I/O
+        # concurrency; only the counter goes under the lock
         self.table.append([e.to_row() for e in entries])
         with self._lock:
-            self._keys.update(e.prompt_hash for e in entries)
-            self._known_version = self.table.latest_version()
             self.writes += len(entries)
         return len(entries)
 
     def stats(self) -> dict[str, Any]:
+        version = self.table.latest_version()
+        entries = len(self.table.keys(version=version))
         with self._lock:
             total = self.hits + self.misses
             return {
@@ -138,6 +121,6 @@ class ResponseCache:
                 "misses": self.misses,
                 "writes": self.writes,
                 "hit_rate": self.hits / total if total else 0.0,
-                "entries": len(self._keys),
-                "version": self.table.latest_version(),
+                "entries": entries,
+                "version": version,
             }

@@ -10,10 +10,19 @@ three *semantics* in ~300 lines (DESIGN.md §2):
 * **transaction log**: ``_log/NNNNNNNN.json`` entries, one per commit,
   listing segment adds/removes.  Commits are atomic via ``O_CREAT|O_EXCL``
   on the next version file — optimistic concurrency: losers retry with the
-  next version number (exactly Delta's protocol),
-* **time travel**: a read at version V replays log entries <= V,
+  next version number (exactly Delta's protocol).  A commit file is
+  published whole by a hard link and never rewritten or deleted, so each
+  handle parses it at most once: the handle keeps the parsed entries and a
+  snapshot of the table at the newest version it has seen, and a read at
+  the latest version parses only the commit files newer than that
+  snapshot (none while the table is unchanged).  Commits from other
+  handles or processes are seen by the next read, as the log's own
+  version decides,
+* **time travel**: a read at version V replays the entries <= V (from the
+  parsed entries the handle holds; never the newer snapshot),
 * **CAS index**: each commit records the set of ``key_column`` values in
-  its segments, so point lookups prune segments without scanning them.
+  its segments; the snapshot inverts them into key -> live segments, so a
+  point lookup reads only the segments that can hold the key.
 
 Crash safety: a writer dying after writing a segment but before its log
 commit leaves an unreferenced file (invisible, garbage-collectable) — the
@@ -26,9 +35,10 @@ import gzip
 import json
 import os
 import random
+import threading
 import time
 import uuid
-from typing import Iterable
+from typing import Callable, Iterable, TypeVar
 
 from repro import obs
 
@@ -50,6 +60,71 @@ def _conflict_backoff(attempt: int) -> None:
     time.sleep(random.uniform(0.0, 0.002) * min(attempt + 1, 8))
 
 
+T = TypeVar("T")
+
+
+class _Snapshot:
+    """The table at one version: its live segments in commit order and,
+    for segments that list their keys, each key's live segments."""
+
+    def __init__(self) -> None:
+        self.version = -1
+        self.live: dict[str, dict] = {}
+        #: commit-order position of each live segment, for newest-first reads
+        self.order: dict[str, int] = {}
+        self.by_key: dict[str, set[str]] = {}
+        #: live segments written without a key list: every lookup reads them
+        self.unkeyed: set[str] = set()
+        self._added = 0
+
+    def fold(self, entry: dict) -> None:
+        """Apply the next version's log entry."""
+        for add in entry.get("add", []):
+            name = add["file"]
+            if name in self.live:
+                self._unindex(self.live[name])
+            else:
+                self.order[name] = self._added
+                self._added += 1
+            self.live[name] = add
+            self._index(add)
+        for name in entry.get("remove", []):
+            seg = self.live.pop(name, None)
+            if seg is not None:
+                self._unindex(seg)
+                del self.order[name]
+        self.version += 1
+
+    def _index(self, seg: dict) -> None:
+        if seg.get("keys") is None:
+            self.unkeyed.add(seg["file"])
+            return
+        for k in seg["keys"]:
+            self.by_key.setdefault(k, set()).add(seg["file"])
+
+    def _unindex(self, seg: dict) -> None:
+        if seg.get("keys") is None:
+            self.unkeyed.discard(seg["file"])
+            return
+        for k in seg["keys"]:
+            names = self.by_key[k]
+            names.discard(seg["file"])
+            if not names:
+                del self.by_key[k]
+
+    def segments(self) -> list[dict]:
+        return list(self.live.values())
+
+    def candidates(self, key: str) -> list[str]:
+        """Live segments that may hold ``key``, newest first."""
+        names = self.by_key.get(key, set()) | self.unkeyed
+        return sorted(names, key=self.order.__getitem__, reverse=True)
+
+    def keys(self) -> tuple[set[str], list[str]]:
+        """Keys the live segments list, and the segments that list none."""
+        return set(self.by_key), list(self.unkeyed)
+
+
 class DeltaLite:
     def __init__(self, path: str, key_column: str | None = None):
         self.path = path
@@ -60,6 +135,13 @@ class DeltaLite:
         # concurrent committers from bunching up on long logs.  Benign
         # under races: the hint only ever lags the truth.
         self._version_hint = -1
+        # parsed log entries (entry i is version i) and the table at the
+        # newest of them; a commit file is immutable once linked, so neither
+        # is ever invalidated, only extended.  The lock guards both; segment
+        # reads happen outside it.
+        self._lock = threading.Lock()
+        self._entries: list[dict] = []
+        self._snap = _Snapshot()
         os.makedirs(os.path.join(path, "_log"), exist_ok=True)
         os.makedirs(os.path.join(path, "data"), exist_ok=True)
 
@@ -79,24 +161,43 @@ class DeltaLite:
         self._version_hint = v
         return v
 
-    def _read_log(self, version: int | None = None) -> list[dict]:
-        with obs.span("storage.read_log") as sp:
-            last = self.latest_version() if version is None else version
-            entries = []
-            for v in range(last + 1):
+    def _catch_up(self, version: int | None) -> None:
+        """Parse the commit files after the snapshot's version, up to
+        ``version`` (the latest when None), and fold them in.  Caller holds
+        the lock."""
+        last = self.latest_version() if version is None else version
+        first = self._snap.version + 1
+        if last < first:
+            return
+        with obs.span("storage.read_log", files=last - first + 1):
+            for v in range(first, last + 1):
                 with open(self._version_path(v)) as f:
-                    entries.append(json.load(f))
-            sp.set(files=len(entries))
-        return entries
+                    entry = json.load(f)
+                self._entries.append(entry)
+                self._snap.fold(entry)
+
+    def _read_log(self, version: int | None = None) -> list[dict]:
+        with self._lock:
+            self._catch_up(version)
+            last = self._snap.version if version is None else version
+            return self._entries[: max(last + 1, 0)]
+
+    def _at(self, version: int | None, query: Callable[[_Snapshot], T]) -> T:
+        """``query`` of the table at ``version`` (the latest when None): on
+        the snapshot, under the lock, when it is that version; else on a
+        replay of the entries up to ``version``."""
+        with self._lock:
+            self._catch_up(version)
+            if version is None or version == self._snap.version:
+                return query(self._snap)
+            entries = self._entries[: max(version + 1, 0)]
+        past = _Snapshot()
+        for entry in entries:
+            past.fold(entry)
+        return query(past)
 
     def _live_segments(self, version: int | None = None) -> list[dict]:
-        live: dict[str, dict] = {}
-        for entry in self._read_log(version):
-            for add in entry.get("add", []):
-                live[add["file"]] = add
-            for rm in entry.get("remove", []):
-                live.pop(rm, None)
-        return list(live.values())
+        return self._at(version, _Snapshot.segments)
 
     # -- writes -----------------------------------------------------------------
 
@@ -221,27 +322,25 @@ class DeltaLite:
         return rows
 
     def lookup(self, key: str, version: int | None = None) -> dict | None:
-        """CAS point lookup: latest row whose key_column equals ``key``."""
+        """CAS point lookup: latest row whose key_column equals ``key``.
+        Reads only the segments the key index names for ``key`` (and those
+        without a key list), newest first; the first that holds the key
+        gives its last matching row."""
         assert self.key_column, "lookup requires a key_column"
-        hit: dict | None = None
-        for seg in self._live_segments(version):
-            keys = seg.get("keys")
-            if keys is not None and str(key) not in keys:
-                continue  # pruned without reading the segment
-            for row in self._read_segment(seg["file"]):
-                if str(row[self.key_column]) == str(key):
-                    hit = row  # later segments win
-        return hit
+        key = str(key)
+        for name in self._at(version, lambda snap: snap.candidates(key)):
+            hit: dict | None = None
+            for row in self._read_segment(name):
+                if str(row[self.key_column]) == key:
+                    hit = row
+            if hit is not None:
+                return hit
+        return None
 
     def keys(self, version: int | None = None) -> set[str]:
-        out: set[str] = set()
-        for seg in self._live_segments(version):
-            if seg.get("keys") is not None:
-                out.update(seg["keys"])
-            else:
-                out.update(
-                    str(r[self.key_column]) for r in self._read_segment(seg["file"])
-                )
+        out, unkeyed = self._at(version, _Snapshot.keys)
+        for name in unkeyed:
+            out.update(str(r[self.key_column]) for r in self._read_segment(name))
         return out
 
     def history(self) -> list[dict]:

@@ -201,22 +201,25 @@ def test_service_and_scheduler_spans_share_the_request_id(tmp_path):
             assert by_sid[s.parent].name == "service.pump"
 
 
-def test_deltalite_lookup_reads_every_commit_file(tmp_path):
-    cache = ResponseCache(str(tmp_path / "c"), CachePolicy.ENABLED)
+def test_deltalite_lookup_reads_each_commit_file_once(tmp_path):
+    writer = ResponseCache(str(tmp_path / "c"), CachePolicy.ENABLED)
     keys = [f"k{i}" for i in range(5)]
     for k in keys:
-        cache.put([CacheEntry(k, "m", "p", "q", "a", 1, 1, 0.0, 0.0)])
+        writer.put([CacheEntry(k, "m", "p", "q", "a", 1, 1, 0.0, 0.0)])
+    cache = ResponseCache(str(tmp_path / "c"), CachePolicy.ENABLED)
     with jax.profiler.trace(str(tmp_path / "t")):
         for k in keys[:3]:
             assert cache.lookup(k).prompt_hash == k
     spans = obs.records().spans
-    by_sid = _by_sid(spans)
-    lookups = [s for s in spans if s.name == "cache.lookup"]
-    logs = [s for s in spans if s.name == "storage.read_log"]
-    assert len(lookups) == len(logs) == 3
-    for s in logs:
-        assert s.attrs == {"files": 5}
-        assert by_sid[s.parent].name == "cache.lookup"
+    lookups = sorted(
+        (s for s in spans if s.name == "cache.lookup"), key=lambda s: s.t0
+    )
+    assert len(lookups) == 3
+    # the fresh handle's first lookup opens every commit file; the later
+    # lookups on the unchanged table open none
+    (log,) = [s for s in spans if s.name == "storage.read_log"]
+    assert log.attrs == {"files": 5}
+    assert log.parent == lookups[0].sid
     segs = [s for s in spans if s.name == "storage.read_segment"]
     assert len(segs) == 3  # the key index prunes every other segment
     table = DeltaLite(str(tmp_path / "d"), key_column="k")
