@@ -16,7 +16,9 @@ number that is not there is exact and has the limit 0):
 * ``logit_gap``: over a sample of finished requests drawn from the seed,
   with the longest among them, the widest gap by which a served token's
   logit lies below the reference's best at its position;
-* ``engine_calls``: engine calls made by a replay (must be none).
+* ``engine_calls``: engine calls made by a replay (must be none);
+* ``replicas_short``: where the mix samples ``per_replica`` requests of
+  each replica, the replicas that finished fewer (must be none).
 
 With ``control=True`` the same numbers are also read off the controls: the
 reference itself computed one precision lower than the program computes,
@@ -29,6 +31,7 @@ The run's ``correct`` is then the :func:`verdict` on the controls' numbers
 from __future__ import annotations
 
 import math
+from collections import Counter
 
 import numpy as np
 
@@ -161,9 +164,12 @@ def half_chunk_ci_gap(probes, results: dict, stats: dict, chunk: int) -> float:
     return gap
 
 
-def sample_requests(probes, seed: int, k: int) -> list[tuple[list[int], list[int]]]:
+def sample_requests(probes, seed: int, k: int,
+                    per_replica: int = 0) -> list[tuple[list[int], list[int]]]:
     """``k`` finished requests of the window drawn from the seed, the one
-    with the most served tokens always among them."""
+    with the most served tokens always among them; with ``per_replica``,
+    the drawn order's first ``per_replica`` of each replica come first, so
+    that every replica that served as many is compared on as many."""
     done = sorted(
         p for p in probes.served if p in probes.done_t and probes.served[p][1]
     )
@@ -173,8 +179,28 @@ def sample_requests(probes, seed: int, k: int) -> list[tuple[list[int], list[int
                                        len(probes.served[p][0]), p))
     rest = [p for p in done if p != longest]
     rng = np.random.default_rng([seed % (1 << 63), 7])
-    pick = [rest[i] for i in rng.permutation(len(rest))[: max(0, k - 1)]]
+    order = [rest[i] for i in rng.permutation(len(rest))]
+    if per_replica:
+        taken = Counter([probes.replica.get(longest, 0)])
+        first, later = [], []
+        for p in order:
+            r = probes.replica.get(p, 0)
+            (first if taken[r] < per_replica else later).append(p)
+            taken[r] += 1
+        order = first + later
+        k = max(k, 1 + len(first))
+    pick = order[: max(0, k - 1)]
     return [probes.served[p][:2] for p in [longest] + pick]
+
+
+def replicas_short(probes, n_replicas: int, per_replica: int) -> int:
+    """Replicas that finished fewer than ``per_replica`` requests with
+    served tokens, and so are compared on fewer."""
+    served = Counter(
+        probes.replica.get(p, 0) for p in probes.served
+        if p in probes.done_t and probes.served[p][1]
+    )
+    return sum(served[r] < per_replica for r in range(n_replicas))
 
 
 def logits(reference, requests, control: bool) -> tuple[dict, dict]:

@@ -36,6 +36,7 @@ for p in (str(HERE), str(ROOT / "src")):
         sys.path.insert(0, p)
 
 import check  # noqa: E402
+import families  # noqa: E402
 import tracing  # noqa: E402
 from probes import Probes  # noqa: E402
 from traffic import Mix  # noqa: E402
@@ -82,30 +83,37 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     )
 
 
-def register_config(config: dict) -> str:
+def register_config(config: dict, family_dir: Path | None = None) -> str:
     """Put the configuration in the program's architecture registry under
-    its own name, after checking it against the file's published sizes."""
+    its own name, after checking it against the file's published sizes.
+
+    The check goes through the module of the file's family,
+    ``families/<model_type>.py`` (:mod:`families`; ``qwen2`` and ``qwen3``
+    today): it refuses published keys the program has no field for, and
+    every program field it maps, with ``padded_vocab``, has to equal the
+    program's after ``program.replace``.  A ``model_type`` with no module
+    stops here; a new family is a new module, and nothing here changes.
+    """
     from repro.configs import ARCHS
 
+    family = families.load(config["model_type"], family_dir or families.HERE)
+    refused = family.refuse(config)
+    if refused:
+        raise ValueError(
+            f"{config['name']}: families/{config['model_type']}.py refuses "
+            f"{refused}: the program has no field for the values stated"
+        )
     prog = config["program"]
     cfg = ARCHS[prog["arch"]]
     if prog.get("replace") or cfg.name != config["name"]:
         cfg = cfg.replace(name=config["name"], **prog.get("replace", {}))
-    c = config
-    want = {
-        "d_model": c["hidden_size"], "n_layers": c["num_hidden_layers"],
-        "n_heads": c["num_attention_heads"], "n_kv_heads": c["num_key_value_heads"],
-        "head_dim": c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"],
-        "d_ff": c["intermediate_size"], "vocab_size": c["vocab_size"],
-        "tie_embeddings": c["tie_word_embeddings"],
-        "qkv_bias": c["model_type"] == "qwen2", "qk_norm": c["model_type"] == "qwen3",
-        "rope_theta": float(c["rope_theta"]), "norm_eps": float(c["rms_norm_eps"]),
-        "padded_vocab": c["weights"]["padded_vocab"],
-    }
+    want = dict(
+        family.program_fields(config), padded_vocab=config["weights"]["padded_vocab"]
+    )
     have = {k: getattr(cfg, k) for k in want}
     if have != want:
         bad = {k: (have[k], want[k]) for k in want if have[k] != want[k]}
-        raise ValueError(f"served config differs from {c['name']}: {bad}")
+        raise ValueError(f"served config differs from {config['name']}: {bad}")
     ARCHS[config["name"]] = cfg
     return config["name"]
 
@@ -179,9 +187,13 @@ def _memory_peak(devices) -> int:
     return int(max(peaks)) if peaks else 0
 
 
-def _serving_stats(session) -> dict:
+def _serving_stats(session) -> tuple[dict, list[dict]]:
+    """The fleet's ``BatcherStats``, summed over replicas, and each
+    replica's ``ServiceStats`` with its own ``BatcherStats`` (none where
+    there is one replica)."""
     snaps = session.serving_stats()
-    return dict(snaps[0].get("batcher", {})) if snaps else {}
+    snap = snaps[0] if snaps else {}
+    return dict(snap.get("batcher", {})), list(snap.get("replica_stats", []))
 
 
 def _union(spans: list[tuple[float, float]], t0: float, t1: float) -> float:
@@ -200,6 +212,8 @@ class Window:
     t1: float = 0.0
     stats0: dict = dataclasses.field(default_factory=dict)
     stats1: dict = dataclasses.field(default_factory=dict)
+    replicas0: list = dataclasses.field(default_factory=list)
+    replicas1: list = dataclasses.field(default_factory=list)
     compiles: int = 0
     trace_dir: str = ""
 
@@ -223,7 +237,7 @@ def _open_window(win: Window, session, counter, trace: bool) -> None:
     if trace:
         win.trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
         jax.profiler.start_trace(win.trace_dir, profiler_options=_profiler_options())
-    win.stats0 = _serving_stats(session)
+    win.stats0, win.replicas0 = _serving_stats(session)
     win.compiles = counter.count
     # name on stderr anything that compiles inside the window
     jax.config.update("jax_log_compiles", True)
@@ -237,7 +251,7 @@ def _close_window(win: Window, session, counter, trace: bool) -> None:
     win.t1 = time.monotonic()
     jax.config.update("jax_log_compiles", False)
     print("window closed", file=sys.stderr, flush=True)
-    win.stats1 = _serving_stats(session)
+    win.stats1, win.replicas1 = _serving_stats(session)
     win.compiles = counter.count - win.compiles
     if trace:
         jax.profiler.stop_trace()
@@ -269,7 +283,8 @@ def drive_suite(cell, mix, session, tasks, seconds, trace, probes, counter):
     page = cell.mix["inference"].get("kv_page_size") or 16
     warm = EvalSuite("warm-up")
     for i, task in enumerate(tasks):
-        if cell.mix["warmup"] == "every_task" or i == 0:
+        # the first task on each replica sweeps its page counts
+        if cell.mix["warmup"] == "every_task" or i < mix.n_replicas:
             warm.add_task(task, mix.warmup_rows(i, page))
     session.run_suite(warm, parallel_jobs=len(warm.task_ids()))
 
@@ -395,6 +410,7 @@ class ReadContext:
     window: Window
     probes: Probes
     events: list | None
+    chips: int = 1
     union: Any = staticmethod(_union)
 
 
@@ -491,9 +507,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, control: bool,
         controls.update(c)
         if not replay:
             reference = _reference(cell.config, weight_seed)
+            per_replica = cell.mix["check"].get("per_replica", 0)
             requests = check.sample_requests(
-                probes, seed, cell.mix["check"]["sample_requests"]
+                probes, seed, cell.mix["check"]["sample_requests"], per_replica
             )
+            if per_replica:
+                numbers["replicas_short"] = check.replicas_short(
+                    probes, mix.n_replicas, per_replica
+                )
             n, c = check.logits(reference, requests, control)
             numbers.update(n)
             controls.update(c)
@@ -520,7 +541,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, control: bool,
         if trace:
             events = tracing.load(win.trace_dir)
             shutil.rmtree(win.trace_dir, ignore_errors=True)
-            ctx = ReadContext(cell.config, cell.mix, peaks, win, probes, events)
+            ctx = ReadContext(
+                cell.config, cell.mix, peaks, win, probes, events, len(used)
+            )
             result["metrics"] = read_per_layer(cell, ctx)
             busy = tracing.busy_seconds(events)
         else:

@@ -15,7 +15,7 @@ def read(ctx):
         return None
     t0, t1 = ctx.window.t0, ctx.window.t1
     bound = sum(
-        work.roofline_seconds(*work.decode_step(ctx.config, ctxs), ctx.peaks)
+        work.roofline_seconds(*work.decode_step(ctx.config, ctxs, ctx), ctx.peaks)
         for a, _b, ctxs in ctx.probes.steps if t0 <= a <= t1
     )
     device = tracing.program_seconds(ctx.events, PROGRAMS)

@@ -1,7 +1,7 @@
 """Whole model over the traced window: the FLOPs the served tokens needed
 (prompt rows not served from the prefix cache, and generated tokens, each
-attending to its live context) over the window, as a share of the chip's
-bf16 peak."""
+attending to its live context) over the window, as a share of the bf16
+peak of the chips the cell uses."""
 
 import work
 
@@ -19,6 +19,6 @@ def read(ctx):
             f"mfu: {len(steps)} decode steps and {len(prefills)} prefills "
             "recorded in the window"
         )
-    flops = sum(work.decode_step(ctx.config, c)[0] for c in steps)
-    flops += sum(work.prefill(ctx.config, n, s)[0] for n, s in prefills)
-    return 100.0 * flops / (ctx.window.seconds * ctx.peaks["bf16_flops"])
+    flops = sum(work.decode_step(ctx.config, c, ctx)[0] for c in steps)
+    flops += sum(work.prefill(ctx.config, n, s, ctx)[0] for n, s in prefills)
+    return 100.0 * flops / (ctx.window.seconds * ctx.chips * ctx.peaks["bf16_flops"])

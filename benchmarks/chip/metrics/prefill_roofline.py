@@ -26,7 +26,7 @@ def read(ctx):
         # decode steps ran, so admissions did: the prefill hook no longer fires
         raise RuntimeError("prefill_roofline: decode steps but no prefills recorded")
     bound = sum(
-        work.roofline_seconds(*work.prefill(ctx.config, n, s), ctx.peaks)
+        work.roofline_seconds(*work.prefill(ctx.config, n, s, ctx), ctx.peaks)
         for n, s in prefills
     )
     device = tracing.program_seconds(ctx.events, PROGRAMS)

@@ -42,6 +42,8 @@ class Probes:
         self._served_by_rid: dict[tuple[int, int], tuple] = {}
         #: prompt -> (prompt token ids, served token ids, finished reason)
         self.served: dict[str, tuple[list[int], list[int], str]] = {}
+        #: prompt -> index of the replica that served it
+        self.replica: dict[str, int] = {}
         #: decode steps: (t0, t1, context lengths of the stepped slots)
         self.steps: list[tuple[float, float, list[int]]] = []
         #: prefills: (t0, t1, prompt tokens, first computed position)
@@ -76,9 +78,10 @@ class Probes:
 
         self._patch(service, "submit", submit)
         for rep in service.replicas:
-            self.attach_engine(rep.engine, serving)
+            self.attach_engine(rep.engine, serving, rep.index)
 
-    def attach_engine(self, engine: Any, serving: bool = True) -> None:
+    def attach_engine(self, engine: Any, serving: bool = True,
+                      replica: int = 0) -> None:
         eid = id(engine)
 
         def stream_submit(orig):
@@ -97,6 +100,7 @@ class Probes:
                     prompt = self._rid_prompt.pop((eid, rid), None)
                     if prompt is not None:
                         self.done_t[prompt] = t
+                        self.replica[prompt] = replica
                         got = self._served_by_rid.pop((eid, rid), None)
                         if got is not None:
                             self.served[prompt] = got

@@ -3,8 +3,8 @@ precision lower (fp8) put in the program's place, the run's own verdict
 reads false, while the program's verdict on the same run reads true.
 
 Run at a size a test can hold, on the CPU: a 4-layer, 512-wide Qwen3 with
-an 8192-row vocabulary under the few-shot mix, and the replay mix over 256
-cached responses, each on three seeds.
+an 8192-row vocabulary under the few-shot mix, on one replica and on four,
+and the replay mix over 256 cached responses, each on three seeds.
 """
 
 import json
@@ -41,6 +41,16 @@ def test_fp8_model_control_fails_the_logit_gap(seed):
     gap = r["program"]["checks"]["logit_gap"]
     assert gap["value"] <= gap["limit"] < r["checks"]["logit_gap"]["value"]
     assert r["checks"]["logit_gap"]["value"] == r["controls"]["logit_gap"]
+
+
+@pytest.mark.parametrize("seed", [5, 2**31 + 6, 7])
+def test_fp8_model_control_fails_the_logit_gap_over_replicas(seed):
+    r = _run("small-qwen3.json", "tiny-fewshot-x4.json", "qwen3-4b.fewshot-x4.json", seed)
+    assert r["program"]["correct"], r["program"]["checks"]
+    assert not r["correct"], r["checks"]
+    gap = r["program"]["checks"]["logit_gap"]
+    assert gap["value"] <= gap["limit"] < r["checks"]["logit_gap"]["value"]
+    assert r["program"]["checks"]["replicas_short"]["value"] == 0
 
 
 @pytest.mark.parametrize("seed", [5, 2**31 + 6, 7])

@@ -9,9 +9,12 @@ with the cell's own limits.  Faults:
 * a decode step that returns its state unchanged (the KV pool);
 * replay: an answer altered where it is produced (the cache read), a
   score altered where it is produced, and half of each chunk left out of
-  the bootstrap partials, the mean taken over the rest.
+  the bootstrap partials, the mean taken over the rest;
+* four replicas: the first token altered on one replica alone, which the
+  check meets because it compares requests of every replica.
 
-The exchange between chips is not a fault these one-chip cells can have.
+The exchange between chips is not a fault these cells can have: the
+four-replica cell's replicas share nothing but the router.
 """
 
 import dataclasses
@@ -24,6 +27,7 @@ import numpy as np
 import pytest
 
 import harness
+from traffic import route
 
 DATA = Path(__file__).resolve().parent / "data"
 LIMITS = Path(harness.HERE) / "limits"
@@ -53,6 +57,11 @@ def fewshot():
 @pytest.fixture
 def replay():
     return _cell("tiny-qwen3.json", "tiny-replay.json", "qwen3-4b.replay.json")
+
+
+@pytest.fixture
+def fewshot_x4():
+    return _cell("tiny-qwen3.json", "tiny-fewshot-x4.json", "qwen3-4b.fewshot-x4.json")
 
 
 def test_sound_runs_are_correct(fewshot, replay):
@@ -139,3 +148,35 @@ def test_half_batch_in_the_bootstrap_is_caught(replay, monkeypatch):
     assert not r["correct"]
     assert r["checks"]["ci_gap"]["value"] > r["checks"]["ci_gap"]["limit"]
 
+
+
+def test_sound_run_over_replicas_is_correct(fewshot_x4):
+    r = _run(fewshot_x4)
+    assert r["correct"], r["checks"]
+    assert r["checks"]["replicas_short"]["value"] == 0
+    assert r["window"]["compiles"] == 0
+
+
+def test_altered_token_on_one_replica_is_caught(fewshot_x4, monkeypatch):
+    from repro.core.engines import LocalJaxEngine
+    from repro.serve.scheduler import ContinuousBatcher
+
+    inf = fewshot_x4.mix["inference"]
+    orig_submit = LocalJaxEngine._submit_locked
+    orig_first = ContinuousBatcher._first_token
+
+    def submit(self, request):
+        rid = orig_submit(self, request)
+        if route(request.prompt, inf["n_replicas"], inf["routing_prefix_len"]) == 3:
+            self._scheduler.altered = True
+        return rid
+
+    def first(self, logits):
+        tok = orig_first(self, logits)
+        return (tok + 1) % self.cfg.vocab_size if getattr(self, "altered", False) else tok
+
+    monkeypatch.setattr(LocalJaxEngine, "_submit_locked", submit)
+    monkeypatch.setattr(ContinuousBatcher, "_first_token", first)
+    r = _run(fewshot_x4)
+    assert not r["correct"]
+    assert r["checks"]["logit_gap"]["value"] > r["checks"]["logit_gap"]["limit"]

@@ -15,12 +15,20 @@ A mix of kind ``suite`` is a set of streaming tasks run together:
   drawn as ``n`` quantiles of a log-normal with ``median`` and ``sigma``,
   clipped to ``[min, max]``.
 
+Where ``inference`` asks for ``n_replicas`` > 1 behind the
+``prefix_affinity`` router, task ``i`` is pinned to replica ``i mod n``: its
+seeded header is drawn again, with a salt of its own, until the router's
+hash (:func:`route`, a copy of ``ReplicaRouter.route``) sends its prompts
+there, and set-up warms every replica alike (:meth:`Mix.warmup_rows`).  With
+one replica the rows are those of a mix that names none.
+
 A mix of kind ``replay`` also lists ``n_responses`` cached responses of
 ``response_words`` words, written ``shard`` at a time, one commit each.
 """
 
 from __future__ import annotations
 
+import hashlib
 import math
 import threading
 from typing import Iterator
@@ -40,6 +48,21 @@ VOCAB = sorted(
     - {"the", "and"}
 )[:4096]
 CHOICES = ("one", "two", "three", "four")
+#: draws tried before a row that has to reach one replica gives up
+MAX_DRAWS = 10_000
+#: index step between two draws of one warm-up row, past any row's index
+DRAW_STRIDE = 1 << 12
+
+
+def route(prompt: str, n_replicas: int, prefix_len: int) -> int:
+    """The replica that the program's ``prefix_affinity`` router sends
+    ``prompt`` to among ``n_replicas`` live ones: the sha256 of its first
+    ``prefix_len`` characters, the digest's first 8 bytes read big-endian,
+    modulo the count (``repro.core.service.ReplicaRouter.route``)."""
+    if n_replicas <= 1:
+        return 0
+    h = hashlib.sha256(prompt[:prefix_len].encode("utf-8", "replace")).digest()
+    return int.from_bytes(h[:8], "big") % n_replicas
 
 
 def quantile_lengths(spec: dict) -> list[int]:
@@ -78,6 +101,13 @@ class Mix:
         self.bodies = quantile_lengths(spec["body_tokens"])
         fixed = n_tokens(self.template.format(header="", question="")) - 1
         self.template_tokens = fixed
+        inf = spec.get("inference", {})
+        self.n_replicas = inf.get("n_replicas", 1)
+        self.prefix_len = 0
+        if self.n_replicas > 1:
+            if inf.get("routing") != "prefix_affinity":
+                raise ValueError("tasks are pinned to replicas by prefix_affinity only")
+            self.prefix_len = inf["routing_prefix_len"]
         self.headers = [self._header(i, t) for i, t in enumerate(spec["tasks"])]
         self.stop = threading.Event()
 
@@ -89,9 +119,39 @@ class Mix:
     def _words(self, rng: np.random.Generator, n: int) -> list[str]:
         return [VOCAB[i] for i in rng.integers(0, len(VOCAB), n)]
 
+    def replica_of(self, prompt: str) -> int:
+        return route(prompt, self.n_replicas, self.prefix_len)
+
     def _header(self, task_index: int, task: dict) -> str:
-        """Seeded k-shot header of exactly ``header_tokens - 1`` tokens."""
-        return self._shots(self._rng(1, task_index), task["header_tokens"] - 1)
+        """Seeded k-shot header of exactly ``header_tokens - 1`` tokens;
+        over replicas, the first draw whose prompts route to the task's
+        replica."""
+        n = task["header_tokens"] - 1
+        if self.n_replicas == 1:
+            return self._shots(self._rng(1, task_index), n)
+        want = task_index % self.n_replicas
+        for attempt in range(MAX_DRAWS):
+            header = self._shots(self._rng(8, task_index, attempt), n)
+            lead = self.template.split("{question}")[0].format(header=header)
+            if len(lead) < self.prefix_len:
+                raise ValueError(
+                    f"task {task['id']}: a header of {n} tokens does not cover "
+                    f"the router's {self.prefix_len} characters"
+                )
+            if self.replica_of(lead) == want:
+                return header
+        raise RuntimeError(f"task {task['id']}: no header routes to replica {want}")
+
+    def _pinned(self, make, replica: int, also=None) -> dict:
+        """The first of ``make(0)``, ``make(1)``, ... whose prompt, and the
+        text ``also(row)`` where given, route to ``replica`` (``make(0)``
+        alone where there is one replica)."""
+        for attempt in range(MAX_DRAWS):
+            row = make(attempt)
+            texts = [self.prompt(row)] + ([also(row)] if also else [])
+            if all(self.replica_of(t) == replica for t in texts):
+                return row
+        raise RuntimeError(f"no warm-up row routes to replica {replica}")
 
     def _shots(self, rng: np.random.Generator, n: int) -> str:
         """``n`` tokens of k-shot lines (empty for ``n <= 0``)."""
@@ -159,10 +219,10 @@ class Mix:
     def warmup_rows(self, task_index: int, page: int) -> list[dict]:
         """Examples that reach every shape the task's traffic can give the
         paged cache: one prompt per count of body pages.  Where prompts
-        share headers, task 0 also sweeps every count of pages a prompt can
-        write or read (:meth:`page_sweep`).  Task 0 pads its list to whole
-        chunks, so the statistics kernel meets only the window's chunk
-        shape."""
+        share headers, the first task of each replica also sweeps every
+        count of pages a prompt can write or read there
+        (:meth:`page_sweep`).  Task 0 pads its list to whole chunks, so the
+        statistics kernel meets only the window's chunk shape."""
         by_pages: dict[int, int] = {}
         for b in sorted(self.bodies):
             by_pages.setdefault(-(-b // page), b)
@@ -173,33 +233,54 @@ class Mix:
             bodies = bodies[:1] + bodies
         base = 1 << 30  # far from the window's example indices
         rows = [self.row(task_index, base + i, b) for i, b in enumerate(bodies)]
-        if task_index == 0:
+        if task_index < self.n_replicas:
             if any(t["header_tokens"] > 1 for t in self.spec["tasks"]):
-                rows += self.page_sweep(base + len(rows), page)
+                rows += self.page_sweep(task_index, base + len(rows), page)
+        if task_index == 0:
             for b in self.bodies[: -len(rows) % self.chunk]:
                 rows.append(self.row(task_index, base + len(rows), b))
         return rows
 
-    def page_sweep(self, first: int, page: int) -> list[dict]:
-        """Prompts of task 0 that make an admission write, and read, every
-        count of pages one prompt can have.  A header that the prefix cache
-        evicts in the window makes the next prompt of its task write all of
-        its pages, or read only the part still cached, and each count of
-        pages is a program of its own.  One prompt with a fresh prefix per
-        count of prompt pages, then one per count of shared pages that
-        shares that many with the longest of them."""
+    def page_sweep(self, task_index: int, first: int, page: int) -> list[dict]:
+        """Prompts of a task that make an admission write, and read, every
+        count of pages one prompt can have, on the replica the task is
+        pinned to.  A header that the prefix cache evicts in the window
+        makes the next prompt of its task write all of its pages, or read
+        only the part still cached, and each count of pages is a program of
+        its own on each replica.  One prompt with a fresh prefix per count
+        of prompt pages, then one per count of shared pages that shares
+        that many with the longest of them.  Over replicas, a row whose
+        draw routes elsewhere is drawn again (header and question), and the
+        longest until the shared headers cut from it route there too."""
+        replica = task_index % self.n_replicas
         top = self.spec["engine"]["max_len"] // 2 // page
         body = min(self.bodies)
-        rows = []
+        rows: list[dict] = []
+
+        def index(attempt: int) -> int:
+            return first + len(rows) + attempt * DRAW_STRIDE
+
         for n in range(1, top + 1):
             # BOS and the header's words, or BOS alone in the body
             h = n * page - body if n * page - body > 1 else 0
-            header = self._shots(self._rng(7, n), h - 1)
-            rows.append(self.row(0, first + len(rows), n * page - h, header))
+
+            def fresh(attempt, n=n, h=h):
+                salt = (7, n) if attempt == 0 else (7, n, replica, attempt)
+                header = self._shots(self._rng(*salt), h - 1)
+                return self.row(task_index, index(attempt), n * page - h, header)
+
+            # the shared headers below join the longest's words on one line
+            shared = (lambda row: " ".join(words(row["header"]))) if n == top else None
+            rows.append(self._pinned(fresh, replica, shared))
         longest = words(rows[-1]["header"])
         for m in range(1, top):
             header = " ".join(longest[: m * page - 1]) + "\n"
-            rows.append(self.row(0, first + len(rows), body, header))
+            rows.append(self._pinned(
+                lambda attempt, header=header: self.row(
+                    task_index, index(attempt), body, header
+                ),
+                replica,
+            ))
         return rows
 
     # -- replay ----------------------------------------------------------------
