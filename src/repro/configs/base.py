@@ -46,8 +46,12 @@ class ModelConfig:
     v_head_dim: int = 0
 
     # --- MoE ----------------------------------------------------------------
-    n_experts: int = 0
+    n_experts: int = 0          # routed experts: the router's width
     n_experts_per_token: int = 0
+    #: experts this layer holds, ``expert_offset`` onward: one chip's share
+    #: of an expert-parallel deployment (0 = all ``n_experts``)
+    n_experts_held: int = 0
+    expert_offset: int = 0
     n_shared_experts: int = 0
     moe_d_ff: int = 0           # per-expert hidden
     first_k_dense: int = 0      # leading dense layers (deepseek-v2)
@@ -84,6 +88,10 @@ class ModelConfig:
     def padded_vocab(self) -> int:
         """Vocab padded to a multiple of 256 so TP-16 shards evenly."""
         return _round_up(self.vocab_size, 256)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:

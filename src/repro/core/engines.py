@@ -94,6 +94,14 @@ _MODEL_QUALITY: dict[str, float] = {
 # -- serving counters ------------------------------------------------------------
 
 
+#: the counters an expert layer's step returns (``models.moe.held_experts``)
+#: and the ``BatcherStats`` fields that sum them, per phase
+EXPERT_COUNTS = ("expert_pairs", "experts_touched", "expert_picks")
+EXPERT_STATS = tuple(
+    f"{n}_{phase}" for phase in ("decode", "prefill") for n in EXPERT_COUNTS
+)
+
+
 @dataclasses.dataclass
 class BatcherStats:
     """Occupancy/throughput counters for a slot-multiplexed decode loop.
@@ -144,6 +152,23 @@ class BatcherStats:
     #: at admission
     queue_wait_s: float = 0.0
     first_token_s: float = 0.0
+    #: expert layers (0 for a dense model), summed over layers, apart for
+    #: decode steps and prefill chunks: (token, held expert) pairs computed,
+    #: held experts given at least one row, and top-k picks of valid rows
+    #: over all experts; each step hands them back with its tokens
+    expert_pairs_decode: int = 0
+    experts_touched_decode: int = 0
+    expert_picks_decode: int = 0
+    expert_pairs_prefill: int = 0
+    experts_touched_prefill: int = 0
+    expert_picks_prefill: int = 0
+
+    def add_expert_counts(self, counts: dict, phase: str) -> None:
+        """Add one step's expert-layer counters (``phase``: ``decode`` or
+        ``prefill``)."""
+        for name in EXPERT_COUNTS:
+            key = f"{name}_{phase}"
+            setattr(self, key, getattr(self, key) + int(counts[name]))
 
     @property
     def tokens_per_step(self) -> float:
@@ -175,6 +200,7 @@ class BatcherStats:
             "pool_pages": self.pool_pages,
             "queue_wait_s": self.queue_wait_s,
             "first_token_s": self.first_token_s,
+            **{k: getattr(self, k) for k in EXPERT_STATS},
         }
 
 

@@ -60,6 +60,7 @@ from typing import Any, Callable, Sequence
 
 from repro import obs
 from repro.core.engines import (
+    EXPERT_STATS,
     InferenceEngine,
     InferenceRequest,
     InferenceResponse,
@@ -350,7 +351,7 @@ def aggregate_batcher_stats(parts: Sequence[dict]) -> dict:
             "tokens_generated", "active_slot_steps", "prefill_recompiles",
             "prefills_deferred", "prefix_pages_hit", "prefix_tokens_saved",
             "cow_copies", "preemptions", "preempted_tokens", "pool_pages",
-            "queue_wait_s", "first_token_s",
+            "queue_wait_s", "first_token_s", *EXPERT_STATS,
         )
     }
     # a rate, not a counter: replicas of one config share it, so take max

@@ -101,14 +101,7 @@ def tblock_fwd(
         a = attn.gqa_full(
             params["attn"], cfg, h, causal=causal, prefix_len=prefix_len, impl=impl
         )
-    x = x + a
-    h = layers.rms_norm(params["ln2"], x, cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
-    if "moe" in params:
-        y, aux = moe.moe_block(params["moe"], cfg, h)
-    else:
-        y = layers.gated_mlp(params["mlp"], h, cfg.act)
-    x = x + y
+    x, aux, _ = _tblock_ffn(params, cfg, x + a)
     return sharding.constrain(x, ("batch", "seq", "embed")), aux
 
 
@@ -161,21 +154,22 @@ def tblock_prefill(
             params["attn"], cfg, h, causal=True, prefix_len=prefix_len,
             impl=impl, kv=(k, v),
         )
-    x, aux = _tblock_ffn(params, cfg, x + a)
+    x, aux, _ = _tblock_ffn(params, cfg, x + a)
     return x, cache, aux
 
 
 def _tblock_ffn(
-    params: dict, cfg: ModelConfig, x: jax.Array
-) -> tuple[jax.Array, jax.Array]:
-    """The block's second half: ``x + ffn(norm(x))`` and the MoE aux loss."""
+    params: dict, cfg: ModelConfig, x: jax.Array, valid: jax.Array | None = None
+) -> tuple[jax.Array, jax.Array, dict]:
+    """The block's second half: ``x + ffn(norm(x))``, the MoE aux loss and
+    the expert layer's counters (none for a dense MLP).  ``valid`` (B, S)
+    marks the rows that are tokens."""
     h = layers.rms_norm(params["ln2"], x, cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
     if "moe" in params:
-        y, aux = moe.moe_block(params["moe"], cfg, h)
-    else:
-        y = layers.gated_mlp(params["mlp"], h, cfg.act)
-    return x + y, aux
+        y, aux, counts = moe.moe_layer(params["moe"], cfg, h, valid)
+        return x + y, aux, counts
+    y = layers.gated_mlp(params["mlp"], h, cfg.act)
+    return x + y, jnp.zeros((), jnp.float32), {}
 
 
 def tblock_prefill_chunk(
@@ -185,13 +179,15 @@ def tblock_prefill_chunk(
     cache: dict,           # {"k", "v"}: (B, S, K, dh), positions < start filled
     positions: jax.Array,  # (W,) start + arange(W)
     length: jax.Array,     # () prompt length; rows at or past it are padding
-) -> tuple[jax.Array, dict]:
+) -> tuple[jax.Array, dict, dict]:
     """Prefill one fixed-width chunk of a GQA prompt into a cache row.
 
     The chunk's keys and values land at positions ``[start, length)``;
     padding rows write nothing, so the row beyond the prompt stays as it
-    was.  Positions before ``start`` are read from ``cache`` as they are
-    (computed by earlier chunks, or gathered from shared prefix pages)."""
+    was, and route to no expert.  Positions before ``start`` are read from
+    ``cache`` as they are (computed by earlier chunks, or gathered from
+    shared prefix pages).  Returns the rows, the cache and the expert
+    layer's counters."""
     w = x.shape[1]
     h = layers.rms_norm(params["ln1"], x, cfg.norm_eps)
     rope_pos = positions[None, :] if cfg.pos_emb == "rope" else None
@@ -207,12 +203,13 @@ def tblock_prefill_chunk(
 
     k_ctx, v_ctx = merge(cache["k"], k), merge(cache["v"], v)
     a = attn.gqa_chunk(params["attn"], cfg, h, k_ctx, v_ctx, positions)
-    x, _ = _tblock_ffn(params, cfg, x + a)
+    valid = jnp.broadcast_to(positions < length, x.shape[:2])
+    x, _, counts = _tblock_ffn(params, cfg, x + a, valid)
     cache = {
         "k": k_ctx.astype(cache["k"].dtype),
         "v": v_ctx.astype(cache["v"].dtype),
     }
-    return x, cache
+    return x, cache, counts
 
 
 def tblock_decode(
@@ -221,19 +218,24 @@ def tblock_decode(
     x: jax.Array,
     cache: dict,
     positions: jax.Array,
-) -> tuple[jax.Array, dict]:
+    valid: jax.Array | None = None,
+) -> tuple[jax.Array, dict, dict]:
+    """One token per row; ``valid`` (B, 1) marks the rows that decode.
+    Returns the rows, the cache and the expert layer's counters."""
     h = layers.rms_norm(params["ln1"], x, cfg.norm_eps)
     if cfg.use_mla:
         a, cache = attn.mla_decode(params["attn"], cfg, h, cache, positions)
     else:
         a, cache = attn.gqa_decode(params["attn"], cfg, h, cache, positions)
-    x = x + a
-    h = layers.rms_norm(params["ln2"], x, cfg.norm_eps)
-    if "moe" in params:
-        y, _ = moe.moe_block(params["moe"], cfg, h)
-    else:
-        y = layers.gated_mlp(params["mlp"], h, cfg.act)
-    return x + y, cache
+    x, _, counts = _tblock_ffn(params, cfg, x + a, valid)
+    return x, cache, counts
+
+
+def _add_counts(total: dict, per_layer: dict) -> dict:
+    """Add a scanned stack's per-layer counters, summed over its layers."""
+    for k, v in per_layer.items():
+        total[k] = total.get(k, 0) + jnp.sum(v)
+    return total
 
 
 # ===========================================================================
@@ -307,13 +309,15 @@ class TransformerLM:
     # -- scan plumbing ---------------------------------------------------------
 
     def _scan_stack(self, stack_params, x, body):
+        stack, whole = moe.split_experts(stack_params)
+
         def scan_body(carry, p_layer):
             h, aux = carry
-            h, aux_l = body(p_layer, h)
+            h, aux_l = body(moe.join_experts(p_layer, whole), h)
             return (h, aux + aux_l), None
 
         (x, aux), _ = maybe_scan(
-            scan_body, (x, jnp.zeros((), jnp.float32)), stack_params
+            scan_body, (x, jnp.zeros((), jnp.float32)), stack
         )
         return x, aux
 
@@ -377,15 +381,17 @@ class TransformerLM:
         new_cache: dict = {}
 
         def run(stack_key: str, x):
+            stack, whole = moe.split_experts(params[stack_key])
+
             def scan_body(h, pc):
                 p_layer, c_layer = pc
                 h, c_layer, _ = tblock_prefill(
-                    p_layer, cfg, h, c_layer, prefix_len=prefix_len,
-                    impl=self.attn_impl,
+                    moe.join_experts(p_layer, whole), cfg, h, c_layer,
+                    prefix_len=prefix_len, impl=self.attn_impl,
                 )
                 return h, c_layer
 
-            x, cs = maybe_scan(scan_body, x, (params[stack_key], cache[stack_key]))
+            x, cs = maybe_scan(scan_body, x, (stack, cache[stack_key]))
             new_cache[stack_key] = cs
             return x
 
@@ -411,7 +417,8 @@ class TransformerLM:
         """Prefill one fixed-width chunk of a text prompt (GQA only) into
         a cache row whose positions before ``start`` are already filled;
         return the logits of position ``length - 1`` (meaningful when the
-        chunk holds it) and the row.
+        chunk holds it), the row and the expert layers' counters, summed
+        over layers (empty for a dense model).
 
         ``start`` and ``length`` are traced, so one program serves every
         chunk of every prompt, whether it starts at 0 or after a shared
@@ -424,25 +431,31 @@ class TransformerLM:
         positions = start + jnp.arange(w, dtype=jnp.int32)
         x = self._embed_tokens(params, tokens, dtype)
         new_cache: dict = {}
+        counts: dict = {}
 
         for stack_key in ("dense_layers", "moe_layers"):
             if stack_key not in params:
                 continue
 
+            stack, whole = moe.split_experts(params[stack_key])
+
             def scan_body(h, pc):
                 p_layer, c_layer = pc
-                return tblock_prefill_chunk(
-                    p_layer, cfg, h, c_layer, positions, length
+                h, c_layer, n = tblock_prefill_chunk(
+                    moe.join_experts(p_layer, whole), cfg, h, c_layer,
+                    positions, length,
                 )
+                return h, (c_layer, n)
 
-            x, new_cache[stack_key] = maybe_scan(
-                scan_body, x, (params[stack_key], cache[stack_key])
+            x, (new_cache[stack_key], n) = maybe_scan(
+                scan_body, x, (stack, cache[stack_key])
             )
+            counts = _add_counts(counts, n)
 
         last = jnp.clip(length - 1 - start, 0, w - 1)
         x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
         x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
-        return self._unembed(params, x, dtype)[:, 0], new_cache
+        return self._unembed(params, x, dtype)[:, 0], new_cache, counts
 
     def decode_step(
         self,
@@ -452,29 +465,38 @@ class TransformerLM:
         positions: jax.Array,  # (B,) position of the new token
         *,
         dtype: Any = jnp.bfloat16,
-    ) -> tuple[jax.Array, dict]:
+        valid: jax.Array | None = None,  # (B,) rows that decode; None = all
+    ) -> tuple[jax.Array, dict, dict]:
+        """One token per row: (logits, cache, the expert layers' counters
+        summed over layers — empty for a dense model)."""
         cfg = self.cfg
         x = self._embed_tokens(params, tokens, dtype)
+        rows = None if valid is None else valid[:, None]
         new_cache: dict = {}
+        counts: dict = {}
 
-        def run(stack_key: str, x):
+        for stack_key in ("dense_layers", "moe_layers"):
+            if stack_key not in params:
+                continue
+
+            stack, whole = moe.split_experts(params[stack_key])
+
             def scan_body(h, pc):
                 p_layer, c_layer = pc
-                h, c_layer = tblock_decode(p_layer, cfg, h, c_layer, positions)
-                return h, c_layer
+                h, c_layer, n = tblock_decode(
+                    moe.join_experts(p_layer, whole), cfg, h, c_layer,
+                    positions, rows,
+                )
+                return h, (c_layer, n)
 
-            x, cs = maybe_scan(scan_body, x, (params[stack_key], cache[stack_key]))
-            new_cache[stack_key] = cs
-            return x
-
-        if "dense_layers" in params:
-            x = run("dense_layers", x)
-        if "moe_layers" in params:
-            x = run("moe_layers", x)
+            x, (new_cache[stack_key], n) = maybe_scan(
+                scan_body, x, (stack, cache[stack_key])
+            )
+            counts = _add_counts(counts, n)
 
         x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
         logits = self._unembed(params, x, dtype)
-        return logits[:, 0], new_cache
+        return logits[:, 0], new_cache, counts
 
 
 # ===========================================================================
@@ -579,7 +601,7 @@ class MambaLM:
         x, cs = maybe_scan(scan_body, x, (params["layers"], cache["layers"]))
         x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
         logits = layers.unembed(params["embed"], x, dtype)
-        return logits[:, 0], {"layers": cs}
+        return logits[:, 0], {"layers": cs}, {}
 
 
 # ===========================================================================
@@ -763,7 +785,7 @@ class HybridLM:
 
             h, new_m = maybe_scan(inner, h, (p_stack, m_cache))
             normed = layers.rms_norm(app_norm, h, cfg.norm_eps)
-            out, new_a = tblock_decode(shared, cfg, normed, a_cache, positions)
+            out, new_a, _ = tblock_decode(shared, cfg, normed, a_cache, positions)
             h = h + (out - normed)
             return h, (new_m, new_a)
 
@@ -785,7 +807,7 @@ class HybridLM:
 
         x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
         logits = layers.unembed(params["embed"], x, dtype)
-        return logits[:, 0], new_cache
+        return logits[:, 0], new_cache, {}
 
 
 # ===========================================================================
@@ -993,7 +1015,7 @@ class EncDecLM:
         )
         x = layers.layer_norm(params["final_norm"], x, cfg.norm_eps)
         logits = layers.unembed(params["embed"], x, dtype)
-        return logits[:, 0], {"self": new_self, "cross": new_cross}
+        return logits[:, 0], {"self": new_self, "cross": new_cross}, {}
 
 
 # ===========================================================================
@@ -1014,7 +1036,8 @@ def build_model(cfg: ModelConfig, **kw: Any):
 
 
 def active_param_count(cfg: ModelConfig, specs: PyTree) -> int:
-    """Parameters touched per token (MoE experts scaled by k/E)."""
+    """Parameters touched per token: a token picks k of the E experts, so
+    the held stacks count k/E of their size (the share of them it meets)."""
     from repro.models.params import map_with_path
 
     total = 0

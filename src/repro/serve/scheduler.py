@@ -39,6 +39,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import obs
 from repro.configs.base import ModelConfig
@@ -61,6 +62,63 @@ _PAGEABLE_FAMILIES = ("dense", "moe")
 
 #: rows of the chunk prefill program (capped at the batcher's ``max_len``)
 PREFILL_WIDTH = 128
+
+
+#: rows of a float32 tile on the TPU
+_F32_TILE_ROWS = 8
+
+
+def _gather_view(pool: jax.Array, tables: jax.Array, ax: int) -> jax.Array:
+    """Gather one pool leaf's pages, ``tables`` (B, pages_per_slot), into
+    the contiguous view with slots at ``ax`` and positions after them.
+
+    Where the leaf's heads fill a float32 tile the pages are gathered
+    page-major and moved into place.  Where they do not (a 4-KV-head model),
+    the pages are taken in place and the view is pinned row-major: left to
+    choose, the TPU compiler tiles such a view over (layers, head_dim), and
+    the decode scan's in-place update of one layer then rewrites every tile
+    of the stacked view (a step ran ~4x slower), and the page-major route
+    keeps a page-major copy of the pool and of the view besides (2.6 GB of
+    temporaries in the expert cell's step)."""
+    b = tables.shape[0]
+    if pool.shape[-2] % _F32_TILE_ROWS == 0:
+        p = jnp.moveaxis(pool, (ax, ax + 1), (0, 1))
+        g = p[tables]  # (B, nP, ps, ...)
+        g = g.reshape((b, -1) + p.shape[2:])
+        return jnp.moveaxis(g, (0, 1), (ax, ax + 1))
+    g = jnp.take(pool, tables, axis=ax)  # (..., B, nP, ps, ...)
+    g = g.reshape(pool.shape[:ax] + (b, -1) + pool.shape[ax + 2 :])
+    return _row_major(g)
+
+
+def _row_major(v: jax.Array) -> jax.Array:
+    """Pin a view whose heads do not fill a float32 tile to its row-major
+    layout (:func:`_gather_view`); one whose heads do keeps its layout."""
+    if v.shape[-2] % _F32_TILE_ROWS == 0:
+        return v
+    return with_layout_constraint(v, Layout(tuple(range(v.ndim))))
+
+
+def _scatter_rows(
+    pool: jax.Array,
+    view: jax.Array,
+    ax: int,
+    positions: jax.Array,
+    write_pages: jax.Array,
+    write_offsets: jax.Array,
+) -> jax.Array:
+    """Write each slot's row at ``positions`` of the view back to its pool
+    page, page-major or in place as :func:`_gather_view` gathered it."""
+    b = positions.shape[0]
+    if pool.shape[-2] % _F32_TILE_ROWS == 0:
+        v = jnp.moveaxis(view, (ax, ax + 1), (0, 1))
+        rows = v[jnp.arange(b), positions]  # (B, ...) the new KV rows
+        p = jnp.moveaxis(pool, (ax, ax + 1), (0, 1))
+        p = p.at[write_pages, write_offsets].set(rows.astype(p.dtype))
+        return jnp.moveaxis(p, (0, 1), (ax, ax + 1))
+    at = (slice(None),) * ax
+    rows = view[at + (jnp.arange(b), positions)]  # (..., B, ...)
+    return pool.at[at + (write_pages, write_offsets)].set(rows.astype(pool.dtype))
 
 
 def _chunk_prefill_supported(cfg: ModelConfig) -> bool:
@@ -225,6 +283,13 @@ class ContinuousBatcher:
         #: are mutually exclusive
         self.device = device
         self.rules = rules
+        if rules is not None and cfg.n_experts:
+            # under sharding rules the expert layer is the capacity dispatch,
+            # which drops tokens by chunk size: serving keeps one expert path
+            raise ValueError(
+                f"{cfg.name}: an expert model is served one device per "
+                f"replica, not on a multi-device replica mesh"
+            )
         self.prefix = cfg.n_vision_tokens if cfg.family == "vlm" else 0
         #: 0 = contiguous per-slot cache; > 0 = paged pool with this page size
         self.page_size = page_size
@@ -341,7 +406,12 @@ class ContinuousBatcher:
                 )
                 self._copy_page_q = jax.jit(self._copy_page_q_impl)
             else:
-                self._paged_decode = jax.jit(self._paged_decode_impl)
+                # an expert model's weights leave no room for a second pool:
+                # its step updates the pool in place
+                self._paged_decode = jax.jit(
+                    self._paged_decode_impl,
+                    donate_argnums=(2,) if cfg.n_experts else (),
+                )
                 self._read_prefix = jax.jit(self._read_prefix_impl)
                 self._write_pages = jax.jit(
                     self._write_pages_impl, static_argnums=(3,)
@@ -443,31 +513,30 @@ class ContinuousBatcher:
         positions: jax.Array,    # (B,)
         write_pages: jax.Array,  # (B,) pool page receiving each slot's new KV
         write_offsets: jax.Array,  # (B,) row within that page
-    ) -> tuple[jax.Array, PyTree]:
+        valid: jax.Array | None = None,  # (B,) active slots (expert models)
+    ) -> tuple:
         """Gather page tables into the contiguous (B, max_len) view the
         decode step understands, run it, scatter the one new KV row per
         slot back to its pool page.  Inactive slots' write targets point
-        at the trash page, so stale positions never corrupt live pages."""
-        b = tokens.shape[0]
-
-        def gather(pool, ax):
-            p = jnp.moveaxis(pool, (ax, ax + 1), (0, 1))
-            g = p[tables]  # (B, nP, ps, ...)
-            g = g.reshape((b, -1) + p.shape[2:])
-            return jnp.moveaxis(g, (0, 1), (ax, ax + 1))
-
-        view = jax.tree.map(gather, pools, self._batch_axes)
-        logits, view = self._decode_fn(params, tokens, view, positions)
-
-        def scatter(pool, leaf, ax):
-            v = jnp.moveaxis(leaf, (ax, ax + 1), (0, 1))
-            rows = v[jnp.arange(b), positions]  # (B, ...) the new KV rows
-            p = jnp.moveaxis(pool, (ax, ax + 1), (0, 1))
-            p = p.at[write_pages, write_offsets].set(rows.astype(p.dtype))
-            return jnp.moveaxis(p, (0, 1), (ax, ax + 1))
-
-        pools = jax.tree.map(scatter, pools, view, self._batch_axes)
-        return logits, pools
+        at the trash page, so stale positions never corrupt live pages.
+        Returns the logits and the pools, and an expert model's step
+        counters after them (a dense step returns what it always has)."""
+        view = jax.tree.map(
+            lambda pool, ax: _gather_view(pool, tables, ax),
+            pools, self._batch_axes,
+        )
+        logits, view, counts = self._decode_fn(
+            params, tokens, view, positions, valid
+        )
+        view = jax.tree.map(_row_major, view)
+        pools = jax.tree.map(
+            lambda pool, leaf, ax: _scatter_rows(
+                pool, leaf, ax, positions, write_pages, write_offsets
+            ),
+            pools, view, self._batch_axes,
+        )
+        # a dense step returns its two results, as callers that wrap it expect
+        return (logits, pools, counts) if counts else (logits, pools)
 
     # -- quantized paged movement (kv_cache_dtype == "int8") ---------------------
     #
@@ -581,7 +650,8 @@ class ContinuousBatcher:
         positions: jax.Array,
         write_pages: jax.Array,
         write_offsets: jax.Array,
-    ) -> tuple[jax.Array, PyTree, PyTree]:
+        valid: jax.Array | None = None,
+    ) -> tuple:
         """Quantized twin of ``_paged_decode_impl``: dequantize at gather,
         decode on the full-precision view, then re-quantize each slot's
         *whole* write page from the updated view (valid rows only — the
@@ -602,7 +672,9 @@ class ContinuousBatcher:
             return jnp.moveaxis(g, (0, 1), (ax, ax + 1))
 
         view = jax.tree.map(gather, pools, scales, self._batch_axes)
-        logits, view = self._decode_fn(params, tokens, view, positions)
+        logits, view, counts = self._decode_fn(
+            params, tokens, view, positions, valid
+        )
 
         page_start = positions - write_offsets
         rows = page_start[:, None] + jnp.arange(ps)[None, :]       # (B, ps)
@@ -624,7 +696,8 @@ class ContinuousBatcher:
         pools, scales = self._map_pool_scale(
             scatter, pools, scales, extra=view
         )
-        return logits, pools, scales
+        out = (logits, pools, scales)
+        return (*out, counts) if counts else out
 
     # -- public API --------------------------------------------------------------
 
@@ -667,23 +740,26 @@ class ContinuousBatcher:
 
     def _prefill_chunks(
         self, row: PyTree, ptoks: list[int], start: int
-    ) -> tuple[jax.Array, PyTree]:
+    ) -> tuple[jax.Array, PyTree, list[dict]]:
         """Prefill prompt positions ``[start, len(ptoks))`` into ``row``
-        chunk by chunk; return the last position's logits and the row."""
+        chunk by chunk; return the last position's logits, the row and each
+        chunk's expert counters (still on the device)."""
         n, w = len(ptoks), self.prefill_width
         if n > self.max_len:
             raise ValueError(f"prompt of {n} tokens exceeds max_len {self.max_len}")
         self._count_prefill_program(w)
+        counts = []
         with obs.span("sched.prefill", tokens=n - start):
             for c in range(start, n, w):
                 chunk = np.zeros((1, w), np.int32)
                 part = ptoks[c : c + w]
                 chunk[0, : len(part)] = part
-                logits, row = self._prefill_chunk(
+                logits, row, chunk_counts = self._prefill_chunk(
                     self.params, jnp.asarray(chunk), row, np.int32(c),
                     np.int32(n),
                 )
-        return logits, row
+                counts.append(chunk_counts)
+        return logits, row, counts
 
     def _first_token(self, logits: jax.Array) -> int:
         """Bring the prefill's greedy token to the host (waits for the
@@ -695,13 +771,22 @@ class ContinuousBatcher:
                 )[0]
             )
 
+    def _count_prefill(self, counts: list[dict]) -> None:
+        """Add an admission's expert counters, computed by the time its
+        first token reached the host (none for a dense model)."""
+        for c in jax.device_get([c for c in counts if c]):
+            self.stats.add_expert_counts(c, "prefill")
+
     def _contiguous_prefill(self, slot: int, req: Request) -> int:
         ptoks = req.prompt_tokens
         with self._compute_ctx():
             with obs.span("sched.row_init"):
                 row_cache = init_params(jax.random.key(1), self._row_specs)
+            counts: list[dict] = []
             if self._chunked:
-                logits, row_cache = self._prefill_chunks(row_cache, ptoks, 0)
+                logits, row_cache, counts = self._prefill_chunks(
+                    row_cache, ptoks, 0
+                )
             else:
                 self._count_prefill_program(len(ptoks))
                 batch = {"tokens": jnp.asarray(np.asarray(ptoks, np.int32)[None])}
@@ -712,7 +797,9 @@ class ContinuousBatcher:
                 with obs.span("sched.prefill", tokens=len(ptoks)):
                     logits, row_cache = self._prefill(self.params, batch, row_cache)
             self.cache = self._insert(self.cache, row_cache, slot)
-            return self._first_token(logits)
+            first_tok = self._first_token(logits)
+        self._count_prefill(counts)
+        return first_tok
 
     def _paged_prefill(self, slot: int, req: Request) -> int:
         """Acquire pages (reusing any resident shared prefix), prefill only
@@ -737,7 +824,7 @@ class ContinuousBatcher:
                         )
                     else:
                         row = self._read_prefix(row, self.cache, shared)
-            logits, row = self._prefill_chunks(row, ptoks, start)
+            logits, row, counts = self._prefill_chunks(row, ptoks, start)
             n_fresh = len(match.page_ids) - match.n_shared_pages
             with obs.span("sched.page_write", pages=n_fresh):
                 fresh = jnp.asarray(
@@ -753,6 +840,7 @@ class ContinuousBatcher:
                         self.cache, row, fresh, match.n_shared_pages
                     )
             first_tok = self._first_token(logits)
+        self._count_prefill(counts)
         self.manager.register(slot, ptoks)
         return first_tok
 
@@ -974,21 +1062,28 @@ class ContinuousBatcher:
             with obs.span("sched.decode.dispatch", slots=len(active)):
                 tokens = jnp.asarray(self.cur_tokens)
                 positions = jnp.asarray(self.slot_pos)
+                # an expert layer routes only the slots that decode; a dense
+                # step takes no mask and compiles as it always has
+                valid = ()
+                if self.cfg.n_experts:
+                    valid = (jnp.asarray(~np.asarray(self.slot_free)),)
                 if self.page_size and self.quantized:
-                    logits, self.cache, self.scales = self._paged_decode_q(
+                    logits, self.cache, self.scales, *counts = self._paged_decode_q(
                         self.params, tokens, self.cache, self.scales,
                         jnp.asarray(tables), positions,
-                        jnp.asarray(wpages), jnp.asarray(woffs),
+                        jnp.asarray(wpages), jnp.asarray(woffs), *valid,
                     )
                 elif self.page_size:
-                    logits, self.cache = self._paged_decode(
+                    logits, self.cache, *counts = self._paged_decode(
                         self.params, tokens, self.cache, jnp.asarray(tables),
                         positions, jnp.asarray(wpages), jnp.asarray(woffs),
+                        *valid,
                     )
                 else:
-                    logits, self.cache = self._decode(
-                        self.params, tokens, self.cache, positions
+                    logits, self.cache, *counts = self._decode(
+                        self.params, tokens, self.cache, positions, *valid
                     )
+                counts = counts[0] if counts else {}
                 if self.temperature > 0:
                     self.key, sub = jax.random.split(self.key)
                     nxt = steps_lib.temperature_sample(
@@ -997,7 +1092,10 @@ class ContinuousBatcher:
                 else:
                     nxt = steps_lib.greedy_sample(logits, self.cfg.vocab_size)
             with obs.span("sched.decode.sync"):
-                nxt = np.asarray(jax.device_get(nxt))
+                nxt, counts = jax.device_get((nxt, counts))
+                nxt = np.asarray(nxt)
+            if counts:
+                self.stats.add_expert_counts(counts, "decode")
 
         with obs.span("sched.decode.commit"):
             for slot in active:

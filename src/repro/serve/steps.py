@@ -50,10 +50,17 @@ def make_prefill_fn(model: Any, cfg: ModelConfig) -> Callable:
 
 
 def make_decode_fn(model: Any, cfg: ModelConfig) -> Callable:
-    """(params, tokens (B,1), cache, positions (B,)) -> (logits (B,Vp), cache)."""
+    """(params, tokens (B,1), cache, positions (B,)[, valid (B,)]) ->
+    (logits (B,Vp), cache, counters).  ``valid`` marks the rows that decode
+    (an expert layer routes only those); the counters are the expert
+    layers' (empty for a dense model)."""
 
-    def decode_fn(params: PyTree, tokens: jax.Array, cache: PyTree, positions):
-        return model.decode_step(params, tokens, cache, positions)
+    def decode_fn(
+        params: PyTree, tokens: jax.Array, cache: PyTree, positions, valid=None
+    ):
+        if valid is None:
+            return model.decode_step(params, tokens, cache, positions)
+        return model.decode_step(params, tokens, cache, positions, valid=valid)
 
     return decode_fn
 
@@ -67,7 +74,7 @@ def make_serve_step(model: Any, cfg: ModelConfig) -> Callable:
         tokens: jax.Array,  # (B, 1) int32 — the tokens sampled last step
         positions: jax.Array,  # (B,) int32 — their positions
     ) -> tuple[jax.Array, PyTree]:
-        logits, cache = model.decode_step(params, tokens, cache, positions)
+        logits, cache, _ = model.decode_step(params, tokens, cache, positions)
         next_tokens = greedy_sample(logits, cfg.vocab_size)
         return next_tokens, cache
 

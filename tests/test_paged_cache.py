@@ -444,3 +444,40 @@ def test_no_leaks_under_interleaved_shared_prefix_churn():
         m.release(owner)
     m.check_no_leaks()
     assert m.stats.prefix_tokens_saved > 0
+
+
+@pytest.mark.parametrize("heads", [4, 8])
+@pytest.mark.parametrize("ax", [0, 1])
+def test_paged_view_gathers_pages_and_scatters_new_rows(heads, ax):
+    """The paged decode's view is each slot's pages in table order, and its
+    new rows land on their pages: page-major where the heads fill a float32
+    tile (8), in place where they do not (4)."""
+    import jax.numpy as jnp
+
+    from repro.serve.scheduler import _gather_view, _scatter_rows
+
+    rng = np.random.default_rng(heads + ax)
+    n_pages, ps, dh = 7, 4, 3
+    lead = (2,) * ax  # a layer axis before the pages
+    pool = rng.standard_normal(lead + (n_pages, ps, heads, dh)).astype(np.float32)
+    tables = np.array([[3, 0, 5], [6, 2, 1]], np.int32)
+    view = np.asarray(_gather_view(jnp.asarray(pool), jnp.asarray(tables), ax))
+    want = np.stack([
+        np.concatenate([np.take(pool, p, axis=ax) for p in t], axis=ax)
+        for t in tables
+    ], axis=ax)
+    np.testing.assert_array_equal(view, want)
+
+    positions = np.array([5, 10], np.int32)
+    pages = tables[np.arange(2), positions // ps]
+    offsets = positions % ps
+    new = view + rng.standard_normal(view.shape).astype(np.float32)
+    got = np.asarray(_scatter_rows(
+        jnp.asarray(pool), jnp.asarray(new), ax, jnp.asarray(positions),
+        jnp.asarray(pages), jnp.asarray(offsets),
+    ))
+    at = (slice(None),) * ax
+    want = pool.copy()
+    for i in range(2):
+        want[at + (pages[i], offsets[i])] = new[at + (i, positions[i])]
+    np.testing.assert_array_equal(got, want)

@@ -68,6 +68,22 @@ def test_bertscore_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows", [368, 1264], ids=["decode16", "chunk128"])
+@pytest.mark.parametrize("k, n", [(2048, 768), (768, 2048)], ids=["up", "down"])
+def test_expert_gmm_compiles(one_chip, rows, k, n):
+    """qwen3-moe's grouped matmul over 16 held experts at its widths, for
+    the row counts of a 16-slot decode step and a 128-row prefill chunk."""
+    from repro.kernels.grouped_matmul.grouped_matmul import expert_gmm
+
+    compiled = _compile(
+        lambda a, b, g: expert_gmm(a, b, g, tile_rows=16),
+        _struct((rows, k), jnp.bfloat16, one_chip),
+        _struct((16, k, n), jnp.bfloat16, one_chip),
+        _struct((16,), jnp.int32, one_chip),
+    )
+    assert "expert_gmm" in compiled.as_text()
+
+
 def test_paged_decode_attention_compiles(one_chip):
     from repro.configs import get_config
     from repro.kernels.decode_attention.ops import paged_decode_attention_bshd
