@@ -231,6 +231,17 @@ def tblock_decode(
     return x, cache, counts
 
 
+def _layer_pages(pool: dict, layer: jax.Array, tables: jax.Array) -> dict:
+    """One layer's KV context from a page pool whose leaves are (layers,
+    pages, page_size, ...): each row's pages in table order, (B,
+    pages_per_slot * page_size, ...).  One gather by layer and page, so no
+    slice of the pool is copied out first."""
+    b = tables.shape[0]
+    return jax.tree.map(
+        lambda p: p[layer, tables].reshape((b, -1) + p.shape[3:]), pool
+    )
+
+
 def _add_counts(total: dict, per_layer: dict) -> dict:
     """Add a scanned stack's per-layer counters, summed over its layers."""
     for k, v in per_layer.items():
@@ -466,10 +477,18 @@ class TransformerLM:
         *,
         dtype: Any = jnp.bfloat16,
         valid: jax.Array | None = None,  # (B,) rows that decode; None = all
+        tables: jax.Array | None = None,  # (B, pages_per_slot) page tables
     ) -> tuple[jax.Array, dict, dict]:
         """One token per row: (logits, cache, the expert layers' counters
-        summed over layers — empty for a dense model)."""
+        summed over layers — empty for a dense model).
+
+        With ``tables``, ``cache`` is a page pool, leaves (layers, pages,
+        page_size, ...): each layer gathers its rows' pages as the scan
+        reaches it, and the step returns in the cache's place only the new
+        KV rows, (layers, B, ...) per leaf, for the caller to write to
+        their pages.  Attention sees the same context either way."""
         cfg = self.cfg
+        b = tokens.shape[0]
         x = self._embed_tokens(params, tokens, dtype)
         rows = None if valid is None else valid[:, None]
         new_cache: dict = {}
@@ -480,17 +499,27 @@ class TransformerLM:
                 continue
 
             stack, whole = moe.split_experts(params[stack_key])
+            stack_cache = cache[stack_key]
+            if tables is not None:
+                # the scan carries layer indices; the pool stays whole
+                stack_cache = jnp.arange(jax.tree.leaves(stack_cache)[0].shape[0])
 
             def scan_body(h, pc):
                 p_layer, c_layer = pc
+                if tables is not None:
+                    c_layer = _layer_pages(cache[stack_key], c_layer, tables)
                 h, c_layer, n = tblock_decode(
                     moe.join_experts(p_layer, whole), cfg, h, c_layer,
                     positions, rows,
                 )
+                if tables is not None:
+                    c_layer = jax.tree.map(
+                        lambda c: c[jnp.arange(b), positions], c_layer
+                    )
                 return h, (c_layer, n)
 
             x, (new_cache[stack_key], n) = maybe_scan(
-                scan_body, x, (stack, cache[stack_key])
+                scan_body, x, (stack, stack_cache)
             )
             counts = _add_counts(counts, n)
 

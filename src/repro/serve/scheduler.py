@@ -24,9 +24,9 @@ leaves become page pools, each slot holds a page table, and a host-side
 :class:`~repro.serve.paged_cache.PagedCacheManager` shares prompt-prefix
 pages across requests by hash chain — a prompt whose leading pages are
 resident skips prefill for them (its chunks start at the first
-non-shared token).  Decode gathers each slot's pages into the contiguous
-view the decode step already understands, then scatters the one new KV
-row back to its pool page.
+non-shared token).  Decode hands the pool and the page tables to the
+model's step, whose layers each gather their own pages; one scatter then
+writes each slot's new KV rows, every layer at once, into the donated pool.
 """
 
 from __future__ import annotations
@@ -39,7 +39,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import obs
 from repro.configs.base import ModelConfig
@@ -64,61 +63,25 @@ _PAGEABLE_FAMILIES = ("dense", "moe")
 PREFILL_WIDTH = 128
 
 
-#: rows of a float32 tile on the TPU
-_F32_TILE_ROWS = 8
-
-
-def _gather_view(pool: jax.Array, tables: jax.Array, ax: int) -> jax.Array:
-    """Gather one pool leaf's pages, ``tables`` (B, pages_per_slot), into
-    the contiguous view with slots at ``ax`` and positions after them.
-
-    Where the leaf's heads fill a float32 tile the pages are gathered
-    page-major and moved into place.  Where they do not (a 4-KV-head model),
-    the pages are taken in place and the view is pinned row-major: left to
-    choose, the TPU compiler tiles such a view over (layers, head_dim), and
-    the decode scan's in-place update of one layer then rewrites every tile
-    of the stacked view (a step ran ~4x slower), and the page-major route
-    keeps a page-major copy of the pool and of the view besides (2.6 GB of
-    temporaries in the expert cell's step)."""
-    b = tables.shape[0]
-    if pool.shape[-2] % _F32_TILE_ROWS == 0:
-        p = jnp.moveaxis(pool, (ax, ax + 1), (0, 1))
-        g = p[tables]  # (B, nP, ps, ...)
-        g = g.reshape((b, -1) + p.shape[2:])
-        return jnp.moveaxis(g, (0, 1), (ax, ax + 1))
-    g = jnp.take(pool, tables, axis=ax)  # (..., B, nP, ps, ...)
-    g = g.reshape(pool.shape[:ax] + (b, -1) + pool.shape[ax + 2 :])
-    return _row_major(g)
-
-
-def _row_major(v: jax.Array) -> jax.Array:
-    """Pin a view whose heads do not fill a float32 tile to its row-major
-    layout (:func:`_gather_view`); one whose heads do keeps its layout."""
-    if v.shape[-2] % _F32_TILE_ROWS == 0:
-        return v
-    return with_layout_constraint(v, Layout(tuple(range(v.ndim))))
-
-
-def _scatter_rows(
+def _write_rows(
     pool: jax.Array,
-    view: jax.Array,
-    ax: int,
-    positions: jax.Array,
+    rows: jax.Array,
     write_pages: jax.Array,
     write_offsets: jax.Array,
 ) -> jax.Array:
-    """Write each slot's row at ``positions`` of the view back to its pool
-    page, page-major or in place as :func:`_gather_view` gathered it."""
-    b = positions.shape[0]
-    if pool.shape[-2] % _F32_TILE_ROWS == 0:
-        v = jnp.moveaxis(view, (ax, ax + 1), (0, 1))
-        rows = v[jnp.arange(b), positions]  # (B, ...) the new KV rows
-        p = jnp.moveaxis(pool, (ax, ax + 1), (0, 1))
-        p = p.at[write_pages, write_offsets].set(rows.astype(p.dtype))
-        return jnp.moveaxis(p, (0, 1), (ax, ax + 1))
-    at = (slice(None),) * ax
-    rows = view[at + (jnp.arange(b), positions)]  # (..., B, ...)
-    return pool.at[at + (write_pages, write_offsets)].set(rows.astype(pool.dtype))
+    """Write each slot's new row, for every layer, into a pool leaf of
+    (layers, pages, page_size, ...): ``rows`` is (layers, B, ...).  One
+    scatter over the pool's rows taken flat, which the TPU compiler does in
+    place for any head count.  Indexed by (page, row) with the layers as a
+    window, the same scatter has it lay a 4-KV-head pool out anew, and back,
+    on every step."""
+    n_layers, n_pages, ps = pool.shape[:3]
+    rest = pool.shape[3:]
+    at = (jnp.arange(n_layers)[:, None] * n_pages + write_pages) * ps
+    at = (at + write_offsets).reshape(-1)
+    flat = pool.reshape((-1,) + rest)
+    flat = flat.at[at].set(rows.reshape((-1,) + rest).astype(pool.dtype))
+    return flat.reshape(pool.shape)
 
 
 def _chunk_prefill_supported(cfg: ModelConfig) -> bool:
@@ -406,11 +369,9 @@ class ContinuousBatcher:
                 )
                 self._copy_page_q = jax.jit(self._copy_page_q_impl)
             else:
-                # an expert model's weights leave no room for a second pool:
-                # its step updates the pool in place
+                # the step writes its rows into the pool in place
                 self._paged_decode = jax.jit(
-                    self._paged_decode_impl,
-                    donate_argnums=(2,) if cfg.n_experts else (),
+                    self._paged_decode_impl, donate_argnums=(2,)
                 )
                 self._read_prefix = jax.jit(self._read_prefix_impl)
                 self._write_pages = jax.jit(
@@ -515,25 +476,19 @@ class ContinuousBatcher:
         write_offsets: jax.Array,  # (B,) row within that page
         valid: jax.Array | None = None,  # (B,) active slots (expert models)
     ) -> tuple:
-        """Gather page tables into the contiguous (B, max_len) view the
-        decode step understands, run it, scatter the one new KV row per
-        slot back to its pool page.  Inactive slots' write targets point
-        at the trash page, so stale positions never corrupt live pages.
-        Returns the logits and the pools, and an expert model's step
-        counters after them (a dense step returns what it always has)."""
-        view = jax.tree.map(
-            lambda pool, ax: _gather_view(pool, tables, ax),
-            pools, self._batch_axes,
+        """Run the decode step over the pools, each layer reading its
+        slots' pages through ``tables``, then write the one new KV row per
+        slot and layer to its pool page in one scatter.  Inactive slots'
+        write targets point at the trash page, so stale positions never
+        corrupt live pages.  Returns the logits and the pools, and an
+        expert model's step counters after them (a dense step returns what
+        it always has)."""
+        logits, rows, counts = self._decode_fn(
+            params, tokens, pools, positions, valid, tables=tables
         )
-        logits, view, counts = self._decode_fn(
-            params, tokens, view, positions, valid
-        )
-        view = jax.tree.map(_row_major, view)
         pools = jax.tree.map(
-            lambda pool, leaf, ax: _scatter_rows(
-                pool, leaf, ax, positions, write_pages, write_offsets
-            ),
-            pools, view, self._batch_axes,
+            lambda pool, r: _write_rows(pool, r, write_pages, write_offsets),
+            pools, rows,
         )
         # a dense step returns its two results, as callers that wrap it expect
         return (logits, pools, counts) if counts else (logits, pools)

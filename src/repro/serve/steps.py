@@ -50,17 +50,23 @@ def make_prefill_fn(model: Any, cfg: ModelConfig) -> Callable:
 
 
 def make_decode_fn(model: Any, cfg: ModelConfig) -> Callable:
-    """(params, tokens (B,1), cache, positions (B,)[, valid (B,)]) ->
-    (logits (B,Vp), cache, counters).  ``valid`` marks the rows that decode
-    (an expert layer routes only those); the counters are the expert
-    layers' (empty for a dense model)."""
+    """(params, tokens (B,1), cache, positions (B,)[, valid (B,)][, tables=])
+    -> (logits (B,Vp), cache, counters).  ``valid`` marks the rows that
+    decode (an expert layer routes only those); the counters are the expert
+    layers' (empty for a dense model).  With ``tables`` (B, pages_per_slot)
+    the cache is a page pool and the step returns the new KV rows in its
+    place (``TransformerLM.decode_step``)."""
 
     def decode_fn(
-        params: PyTree, tokens: jax.Array, cache: PyTree, positions, valid=None
+        params: PyTree, tokens: jax.Array, cache: PyTree, positions, valid=None,
+        *, tables=None,
     ):
-        if valid is None:
-            return model.decode_step(params, tokens, cache, positions)
-        return model.decode_step(params, tokens, cache, positions, valid=valid)
+        kw = {}
+        if valid is not None:
+            kw["valid"] = valid
+        if tables is not None:
+            kw["tables"] = tables
+        return model.decode_step(params, tokens, cache, positions, **kw)
 
     return decode_fn
 

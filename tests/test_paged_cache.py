@@ -447,37 +447,35 @@ def test_no_leaks_under_interleaved_shared_prefix_churn():
 
 
 @pytest.mark.parametrize("heads", [4, 8])
-@pytest.mark.parametrize("ax", [0, 1])
-def test_paged_view_gathers_pages_and_scatters_new_rows(heads, ax):
-    """The paged decode's view is each slot's pages in table order, and its
-    new rows land on their pages: page-major where the heads fill a float32
-    tile (8), in place where they do not (4)."""
+@pytest.mark.parametrize("extra_layers", [0, 1])
+def test_paged_view_gathers_pages_and_scatters_new_rows(heads, extra_layers):
+    """A layer's paged context is each slot's pages of that layer in table
+    order, and the new rows of every layer land on their pages, for pools
+    of one layer and of two, with 4 and 8 KV heads."""
     import jax.numpy as jnp
 
-    from repro.serve.scheduler import _gather_view, _scatter_rows
+    from repro.models.model import _layer_pages
+    from repro.serve.scheduler import _write_rows
 
-    rng = np.random.default_rng(heads + ax)
-    n_pages, ps, dh = 7, 4, 3
-    lead = (2,) * ax  # a layer axis before the pages
-    pool = rng.standard_normal(lead + (n_pages, ps, heads, dh)).astype(np.float32)
+    rng = np.random.default_rng(heads + extra_layers)
+    n_layers, n_pages, ps, dh = 1 + extra_layers, 7, 4, 3
+    pool = rng.standard_normal((n_layers, n_pages, ps, heads, dh)).astype(np.float32)
     tables = np.array([[3, 0, 5], [6, 2, 1]], np.int32)
-    view = np.asarray(_gather_view(jnp.asarray(pool), jnp.asarray(tables), ax))
-    want = np.stack([
-        np.concatenate([np.take(pool, p, axis=ax) for p in t], axis=ax)
-        for t in tables
-    ], axis=ax)
-    np.testing.assert_array_equal(view, want)
+    for layer in range(n_layers):
+        view = _layer_pages({"k": jnp.asarray(pool)}, jnp.int32(layer),
+                            jnp.asarray(tables))["k"]
+        want = np.stack([np.concatenate(list(pool[layer, t])) for t in tables])
+        np.testing.assert_array_equal(np.asarray(view), want)
 
     positions = np.array([5, 10], np.int32)
     pages = tables[np.arange(2), positions // ps]
     offsets = positions % ps
-    new = view + rng.standard_normal(view.shape).astype(np.float32)
-    got = np.asarray(_scatter_rows(
-        jnp.asarray(pool), jnp.asarray(new), ax, jnp.asarray(positions),
-        jnp.asarray(pages), jnp.asarray(offsets),
+    rows = rng.standard_normal((n_layers, 2, heads, dh)).astype(np.float32)
+    got = np.asarray(_write_rows(
+        jnp.asarray(pool), jnp.asarray(rows), jnp.asarray(pages),
+        jnp.asarray(offsets),
     ))
-    at = (slice(None),) * ax
     want = pool.copy()
     for i in range(2):
-        want[at + (pages[i], offsets[i])] = new[at + (i, positions[i])]
+        want[:, pages[i], offsets[i]] = rows[:, i]
     np.testing.assert_array_equal(got, want)

@@ -167,3 +167,22 @@ def test_full_width_decode_step_fits_one_chip(one_chip):
     )
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("kv_heads", [4, 8])
+def test_paged_row_write_is_in_place_for_any_head_count(one_chip, kv_heads):
+    """The paged step's one scatter of every layer's new rows, at the
+    expert cell's pool (48 layers, 305 pages of 16 rows, head 128): with
+    the pool donated it keeps no copy of it, with 4 KV heads as with 8."""
+    from repro.serve.scheduler import _write_rows
+
+    pool = _struct((48, 305, 16, kv_heads, 128), jnp.float32, one_chip)
+    slots = _struct((16,), jnp.int32, one_chip)
+    compiled = jax.jit(_write_rows, donate_argnums=(0,)).lower(
+        pool, _struct((48, 16, kv_heads, 128), jnp.float32, one_chip),
+        slots, slots,
+    ).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 48 * 305 * 16 * kv_heads * 128 * 4
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
